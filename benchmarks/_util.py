@@ -115,10 +115,13 @@ def read_rows(name: str) -> list[dict]:
 
 
 def run_subprocess(code: str, n_devices: int = 8, timeout: int = 1200) -> str:
-    """Run a snippet with N fake devices; return stdout."""
+    """Run a snippet with N fake CPU devices; return stdout. The child is
+    held to the CPU: an accelerator belongs to the parent process."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [SRC, str(ROOT), env.get("PYTHONPATH", "")])
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=timeout)
     if proc.returncode != 0:

@@ -24,7 +24,7 @@ from repro.kernels import ops, ref, use_backend
 
 
 def _util(flops: float, bytes_hbm: float, dtype="bfloat16") -> float:
-    peak = CHIP.peak_bf16_flops if dtype == "bfloat16" else CHIP.peak_fp32_flops
+    peak = CHIP.peak(dtype)
     t_c = flops / peak
     t_m = bytes_hbm / CHIP.hbm_bw
     return t_c / max(t_c, t_m)

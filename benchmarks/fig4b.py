@@ -7,7 +7,8 @@ dedicated widening dot-product units.
 
 TPU analogue: fp32 → bf16 → fp8 feeding the MXU with fp32 accumulation
 (``preferred_element_type``), the MXU's native widening mode. We report:
-  * roofline throughput per precision (the MXU 2x-per-halving ladder),
+  * the published peak per precision where v5e has one (bf16; its int8
+    MXU peak is 2x bf16, and it publishes none for fp32 or fp8),
   * numerical error of widening vs same-precision accumulation (why
     expanding accumulation is the right default — the paper's C2 insight),
   * measured CPU wall-time ratios as a sanity signal only.
@@ -58,21 +59,22 @@ def main() -> list[dict]:
         _, t = timeit(lambda: jnp.dot(x, w,
                                       preferred_element_type=jnp.float32),
                       n=3)
-        peak = dtype_peak_flops({"fp32": "float32", "bf16": "bfloat16",
-                                 "fp8_e4m3": "float8_e4m3fn"}[name])
+        # v5e publishes bf16 and int8 MXU peaks only: fp32 and fp8 have none
+        peak = dtype_peak_flops("bfloat16") if name == "bf16" else None
         rows.append({
             "precision": name,
-            "peak_tflops_per_chip": round(peak / 1e12, 1),
-            "roofline_vs_bf16": round(peak / dtype_peak_flops("bfloat16"), 2),
+            "peak_tflops_per_chip": (round(peak / 1e12, 1) if peak
+                                     else "not published"),
+            "roofline_vs_bf16": (round(peak / dtype_peak_flops("bfloat16"), 2)
+                                 if peak else "not published"),
             "err_widening_accum": round(_err(wide, oracle), 5),
             "err_narrow_accum": round(_err(narrow, oracle), 5),
             "cpu_ms": round(t * 1e3, 2),
         })
 
-    # the ladder must double per halving, and widening accumulation must be
-    # strictly more accurate than narrow accumulation at every precision
+    # widening accumulation must be strictly more accurate than narrow
+    # accumulation at every precision
     assert rows[1]["roofline_vs_bf16"] == 1.0
-    assert rows[2]["roofline_vs_bf16"] == 2.0
     for r in rows[1:]:
         assert r["err_widening_accum"] < r["err_narrow_accum"]
     emit(rows, "fig4b")

@@ -63,6 +63,8 @@ def main() -> None:
                          "append one summary CSV row per suite to "
                          "experiments/bench/metrics_runs.csv")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.metrics_dir:
         # suites resolve their report path through _util.metrics_path
         os.makedirs(args.metrics_dir, exist_ok=True)

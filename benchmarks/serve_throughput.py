@@ -5,7 +5,8 @@ Three sections, all emitting into one ``serve_throughput.csv``:
 * **layout** — dense vs paged KV cache on a mixed-length greedy trace:
   identical tokens, paged KV bytes/request drops at mixed lengths, chunked
   prefill compiles ONE shape.
-* **scale-out** (``--devices N``) — subprocess runs with fake CPU devices:
+* **scale-out** (``--devices N``) — 1 vs N devices: CPU-only child
+  processes with fake devices on CPU, both sizes in this process on chips:
   a KV-head-sharded pool under the same *per-device* HBM budget must admit
   >= 3x the concurrent requests of single-device serving (at N = 4) at
   <= 1.1x the per-device KV bytes per request, with exact greedy parity.
@@ -110,53 +111,53 @@ def _layout_rows() -> list[dict]:
     return rows
 
 
-# one subprocess per device count: jax locks the device count at first
-# init, so 1-device and N-device engines cannot share an interpreter
-_SCALE_SNIPPET = """
-import json
-import numpy as np
-import jax
+def _scale_run(n_dev: int, budget: int) -> dict:
+    """One engine over the first ``n_dev`` devices under a per-device KV
+    budget: a KV-head-sharded serve mesh when ``n_dev > 1``."""
+    import jax
 
-N_DEV = {n_dev}
-KV_BUDGET = {budget}
+    from repro.configs import get_arch, reduced
+    from repro.models import init
+    from repro.serve import Request, ServeEngine
 
-from repro.configs import get_arch, reduced
-from repro.models import init
-from repro.serve import Request, ServeEngine
+    cfg = reduced(get_arch("qwen3-0.6b")).replace(
+        n_layers=2, d_model=64, n_heads=8, n_kv_heads=8, head_dim=16,
+        d_ff=128, vocab_size=512, dtype="float32")
+    params = init(jax.random.PRNGKey(0), cfg)
+    part = None
+    if n_dev > 1:
+        from jax.sharding import Mesh
 
-cfg = reduced(get_arch("qwen3-0.6b")).replace(
-    n_layers=2, d_model=64, n_heads=8, n_kv_heads=8, head_dim=16,
-    d_ff=128, vocab_size=512, dtype="float32")
-params = init(jax.random.PRNGKey(0), cfg)
-part = None
-if N_DEV > 1:
-    from repro.configs.base import StrategyConfig
-    from repro.core.sharding import Partitioner
-    mesh = jax.make_mesh((1, N_DEV), ("data", "model"))
-    part = Partitioner(mesh,
-                       StrategyConfig(name="ramora", tensor_parallel=True),
-                       cfg, mode="serve")
-engine = ServeEngine(cfg, params, max_slots=16, max_len=48, part=part,
-                     paged=True, page_size=8, prefill_chunk=16,
-                     kv_budget_bytes=KV_BUDGET)
-rng = np.random.default_rng(0)
-reqs = [Request(uid=i,
-                prompt=rng.integers(0, cfg.vocab_size, 16).astype(np.int32),
-                max_new_tokens=16) for i in range(20)]
-results = engine.run(reqs)
-assert engine.allocator.n_live == 0, "leaked blocks"
-print(json.dumps({{
-    "tokens": [r.tokens for r in results],
-    "kv_shard": engine._kv_shard,
-    "n_blocks": engine.n_blocks,
-    "max_concurrency": engine.stats["max_concurrency"],
-    "kv_bytes_per_request_dev":
-        engine.stats["kv_bytes_alloc_dev"] // len(results),
-}}))
-"""
+        from repro.configs.base import StrategyConfig
+        from repro.core.sharding import Partitioner
+        mesh = Mesh(np.asarray(jax.devices()[:n_dev]).reshape(1, n_dev),
+                    ("data", "model"))
+        part = Partitioner(mesh,
+                           StrategyConfig(name="ramora", tensor_parallel=True),
+                           cfg, mode="serve")
+    engine = ServeEngine(cfg, params, max_slots=16, max_len=48, part=part,
+                         paged=True, page_size=8, prefill_chunk=16,
+                         kv_budget_bytes=budget)
+    rng = np.random.default_rng(0)
+    reqs = [Request(uid=i,
+                    prompt=rng.integers(0, cfg.vocab_size, 16).astype(
+                        np.int32),
+                    max_new_tokens=16) for i in range(20)]
+    results = engine.run(reqs)
+    assert engine.allocator.n_live == 0, "leaked blocks"
+    return {
+        "tokens": [r.tokens for r in results],
+        "kv_shard": engine._kv_shard,
+        "n_blocks": engine.n_blocks,
+        "max_concurrency": engine.stats["max_concurrency"],
+        "kv_bytes_per_request_dev":
+            engine.stats["kv_bytes_alloc_dev"] // len(results),
+    }
 
 
 def _scale_rows(n_dev: int) -> list[dict]:
+    import jax
+
     # the same PER-DEVICE budget on both sides: 16 blocks' worth of a
     # 2-layer K=8 hd=16 fp32 pool (2 * 8rows * 8K * 16hd * 4B = 16 KiB
     # per block) — single-device serving admits 4 concurrent 32-token
@@ -165,10 +166,18 @@ def _scale_rows(n_dev: int) -> list[dict]:
     budget = 16 * 16384
     runs = {}
     for nd in (1, n_dev):
-        out = run_subprocess(
-            _SCALE_SNIPPET.format(n_dev=nd, budget=budget),
-            n_devices=max(nd, 1))
-        runs[nd] = json.loads(out.strip().splitlines()[-1])
+        if jax.default_backend() == "cpu":
+            # fake CPU devices: jax fixes the device count at first init,
+            # so each count gets a CPU-only child process
+            out = run_subprocess(
+                "import json\n"
+                "from benchmarks.serve_throughput import _scale_run\n"
+                f"print(json.dumps(_scale_run({nd}, {budget})))",
+                n_devices=max(nd, 1))
+            runs[nd] = json.loads(out.strip().splitlines()[-1])
+        else:
+            # real chips belong to this process: both sizes run here
+            runs[nd] = _scale_run(nd, budget)
     base, multi = runs[1], runs[n_dev]
     assert multi["tokens"] == base["tokens"], \
         "sharded serving diverged from single-device greedy outputs"
@@ -332,9 +341,9 @@ def _trace_smoke(trace_out: str, metrics_out: str) -> None:
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=0,
-                    help="also run the SPMD scale-out comparison: a "
-                         "subprocess pair (1 vs N fake devices) under the "
-                         "same per-device KV budget")
+                    help="also run the SPMD scale-out comparison: 1 vs N "
+                         "devices under the same per-device KV budget "
+                         "(fake-device child processes on CPU)")
     ap.add_argument("--dry-run", action="store_true",
                     help="run the mixed-trace tracing smoke (prefix hits, "
                          "preemption, fork, split pools, spec decode) and "

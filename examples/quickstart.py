@@ -38,11 +38,13 @@ print(f"after 1 step: loss={float(metrics['loss']):.4f}  "
 
 # 4) Show the production sharding plan (what the 16x16 dry-run uses) for a
 #    few parameters — logical axes -> mesh axes, no devices needed.
-from repro.core.sharding import Partitioner, abstract_mesh
+from jax.sharding import AbstractMesh
+
+from repro.core.sharding import Partitioner
 
 full = get_arch("qwen3-0.6b")
 shape = ShapeConfig("train_4k", "train", 4096, 256)
-mesh = abstract_mesh((16, 16), ("data", "model"))
+mesh = AbstractMesh((16, 16), ("data", "model"))
 part = Partitioner(mesh, strategy("ramora"), full, shape)
 print("\nproduction sharding plan (16x16 ramora):")
 for path, shp in [("embed/table", (151936, 1024)),

@@ -25,20 +25,6 @@ from jax.sharding import PartitionSpec as P
 PyTree = Any
 
 
-def shard_map_compat(body, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: new API (full-manual via
-    ``axis_names``, ``check_vma``) when present, else the
-    ``jax.experimental.shard_map`` spelling (always manual over every mesh
-    axis, ``check_rep`` instead of ``check_vma``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(mesh.axis_names), check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 # --------------------------------------------------------------------------
 # hierarchical (in-network style) all-reduce
 # --------------------------------------------------------------------------
@@ -75,8 +61,8 @@ def hierarchical_allreduce(x: jnp.ndarray, mesh: Mesh, *,
     # full-manual shard_map: jax rejects out_specs=P() when axis_names is a
     # strict subset of the mesh axes; manual-ing every axis keeps semantics
     # (inputs here are replicated) and sidesteps the partial-manual limits.
-    return shard_map_compat(body, mesh=mesh, in_specs=spec,
-                            out_specs=spec)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=spec,
+                         out_specs=spec, check_vma=False)(x)
 
 
 def flat_allreduce(x: jnp.ndarray, mesh: Mesh, axes: tuple[str, ...]):
@@ -85,8 +71,8 @@ def flat_allreduce(x: jnp.ndarray, mesh: Mesh, axes: tuple[str, ...]):
     def body(xl):
         return jax.lax.psum(xl, axes)
 
-    return shard_map_compat(body, mesh=mesh, in_specs=P(),
-                            out_specs=P())(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(),
+                         out_specs=P(), check_vma=False)(x)
 
 
 # --------------------------------------------------------------------------
@@ -98,8 +84,8 @@ def multicast(x: jnp.ndarray, mesh: Mesh, axis: str, root: int = 0):
         full = jax.lax.all_gather(xl, axis, axis=0, tiled=False)
         return full[root]
 
-    return shard_map_compat(body, mesh=mesh, in_specs=P(),
-                            out_specs=P())(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(),
+                         out_specs=P(), check_vma=False)(x)
 
 
 def barrier(mesh: Mesh, axes: tuple[str, ...]):
@@ -108,8 +94,8 @@ def barrier(mesh: Mesh, axes: tuple[str, ...]):
         return jax.lax.psum(t, axes)
 
     tok = jnp.ones((), jnp.int32)
-    return shard_map_compat(body, mesh=mesh, in_specs=P(),
-                            out_specs=P())(tok)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(),
+                         out_specs=P(), check_vma=False)(tok)
 
 
 # --------------------------------------------------------------------------
@@ -146,5 +132,5 @@ def compressed_psum(x: jnp.ndarray, mesh: Mesh, axes: tuple[str, ...],
         mean = (qs.astype(jnp.float32) * ss).sum(0) / n
         return mean, local_err
 
-    return shard_map_compat(body, mesh=mesh, in_specs=P(),
-                            out_specs=(P(), P()))(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(),
+                         out_specs=(P(), P()), check_vma=False)(x)

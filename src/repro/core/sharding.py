@@ -19,7 +19,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AbstractMesh, AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig, StrategyConfig
@@ -29,15 +29,18 @@ PyTree = Any
 log = logging.getLogger("repro.sharding")
 
 
-def abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """``jax.sharding.AbstractMesh`` across jax versions: the new
-    ``(shape, axis_names)`` spelling when accepted, else the 0.4.x
-    ``((name, size), ...)`` shape-tuple form."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+def _auto_axes(mesh):
+    """``mesh`` with every axis in Auto mode. The rules below are hints to
+    the partitioner. ``jax.make_mesh`` makes Explicit axes by default, and
+    under those a sharding is part of each array's type: the FSDP embed
+    gather then asks for one axis on two dims (``DuplicateSpecError``) and
+    every contraction over a sharded dim must name its output sharding."""
+    if AxisType.Explicit not in mesh.axis_types:
+        return mesh
+    auto = (AxisType.Auto,) * len(mesh.axis_names)
+    if isinstance(mesh, Mesh):
+        return Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
+    return AbstractMesh(mesh.axis_sizes, mesh.axis_names, axis_types=auto)
 
 
 def _axes_size(mesh: Mesh, axes) -> int:
@@ -49,7 +52,7 @@ def _axes_size(mesh: Mesh, axes) -> int:
 class Partitioner:
     def __init__(self, mesh: Mesh, strategy: StrategyConfig, cfg: ModelConfig,
                  shape: ShapeConfig | None = None, mode: str = "train"):
-        self.mesh = mesh
+        self.mesh = mesh = _auto_axes(mesh)
         self.strategy = strategy
         self.cfg = cfg
         self.shape = shape

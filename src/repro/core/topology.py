@@ -13,20 +13,61 @@ from dataclasses import dataclass
 
 @dataclass(frozen=True)
 class ChipSpec:
-    """TPU v5e-class chip (the dry-run target)."""
-    name: str = "tpu-v5e"
-    peak_bf16_flops: float = 197e12     # FLOP/s per chip
-    peak_fp32_flops: float = 98.5e12    # MXU fp32 ~ half bf16
-    hbm_bytes: float = 16 * 1024**3
-    hbm_bw: float = 819e9               # B/s
-    ici_link_bw: float = 50e9           # B/s per link (~ the paper's D2D PHY bundle)
-    ici_links_per_chip: int = 4         # 2D torus: ±x, ±y
-    vmem_bytes: float = 128 * 1024**2
+    """Published peaks of one accelerator chip."""
+    name: str
+    peak_ops: tuple[tuple[str, float], ...]   # (dtype, op/s per chip)
+    hbm_bytes: float
+    hbm_bw: float                       # B/s
+    ici_link_bw: float                  # B/s per link (~ the paper's D2D PHY bundle)
+    ici_links_per_chip: int             # 2D torus: ±x, ±y
+    vmem_bytes: float
+
+    def peak(self, dtype: str) -> float:
+        """Peak op/s per chip for a compute dtype; a dtype the source does
+        not list raises rather than borrowing another dtype's peak."""
+        table = dict(self.peak_ops)
+        if str(dtype) not in table:
+            raise KeyError(f"{self.name}: no published peak for dtype "
+                           f"{dtype!r} (known: {sorted(table)})")
+        return table[str(dtype)]
+
+    @property
+    def peak_bf16_flops(self) -> float:
+        return self.peak("bfloat16")
+
+
+#: Peak table keyed by ``jax.Device.device_kind``. Source: Google Cloud
+#: documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at
+#: 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (4 links of 50 GB/s).
+#: v5e has no fp8 MXU, so there is no fp8 entry.
+PEAKS: dict[str, ChipSpec] = {
+    "TPU v5 lite": ChipSpec(
+        name="tpu-v5e",
+        peak_ops=(("bfloat16", 197e12), ("int8", 393e12)),
+        hbm_bytes=16 * 1024**3,
+        hbm_bw=819e9,
+        ici_link_bw=50e9,
+        ici_links_per_chip=4,
+        vmem_bytes=128 * 1024**2,
+    ),
+}
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """The peak-table entry for a device kind; unknown kinds raise."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak table entry for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
+
+
+#: The dry-run and analytic roofline target.
+CHIP = PEAKS["TPU v5 lite"]
 
 
 @dataclass(frozen=True)
 class PodSpec:
-    chip: ChipSpec = ChipSpec()
+    chip: ChipSpec = CHIP
     chips_x: int = 16
     chips_y: int = 16
     # inter-pod (DCN / "D2D") — slower than ICI, like Occamy's narrow D2D link
@@ -41,20 +82,14 @@ class PodSpec:
         return self.n_chips * self.chip.peak_bf16_flops
 
 
-CHIP = ChipSpec()
 POD = PodSpec()
 
 
-def dtype_peak_flops(dtype: str) -> float:
-    """Peak FLOP/s per chip for a compute dtype (paper Fig. 4b analogue:
-    halving precision doubles throughput; fp8 feeds the MXU at 2x bf16)."""
-    return {
-        "float32": CHIP.peak_fp32_flops,
-        "bfloat16": CHIP.peak_bf16_flops,
-        "float16": CHIP.peak_bf16_flops,
-        "float8_e4m3fn": 2 * CHIP.peak_bf16_flops,
-        "float8_e5m2": 2 * CHIP.peak_bf16_flops,
-    }.get(str(dtype), CHIP.peak_bf16_flops)
+def dtype_peak_flops(dtype: str, device_kind: str = "TPU v5 lite") -> float:
+    """Peak op/s per chip of ``device_kind`` for a compute dtype (paper
+    Fig. 4b analogue: halving precision, bf16 -> int8, doubles it). Unknown
+    devices and dtypes raise."""
+    return chip_spec(device_kind).peak(dtype)
 
 
 def roofline_time(flops: float, bytes_hbm: float, bytes_collective: float,

@@ -27,6 +27,7 @@ or per-shape tuning is a registry entry — not a cross-cutting edit.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import inspect
@@ -118,9 +119,13 @@ def requested_backend() -> str | None:
 
 def kernel_scope_active() -> bool:
     """True inside an explicit ``use_backend`` scope that selects the Pallas
-    kernels. The one predicate model call sites (dense, MoE gather,
-    diag_scan) gate on — ambient auto-detection never flips it."""
-    return requested_backend() in KERNEL_BACKENDS
+    kernels — ``auto`` included where the platform resolves it to them (a
+    TPU). The one predicate model call sites (dense, MoE gather, diag_scan)
+    gate on — ambient auto-detection without a scope never flips it."""
+    be = requested_backend()
+    if be == "auto":
+        be = negotiated_model_backend(be)
+    return be in KERNEL_BACKENDS
 
 
 def spmd_xla_scope():
@@ -289,6 +294,7 @@ class OpRegistry:
         self._blocks: dict[tuple[str, str], BlockSpec] = {}
         self._bucket_fns: dict[str, Callable[[OpRequest], str]] = {}
         self._sig_cache: dict[Callable, tuple[frozenset[str], bool]] = {}
+        self._resolved: collections.Counter = collections.Counter()
 
     # ---- registration ----------------------------------------------------
     def register(self, op: str, name: str, *, backends: Iterable[str],
@@ -341,6 +347,18 @@ class OpRegistry:
                               if isinstance(v, (int, float, str, bool,
                                                 type(None)))))
         return OpRequest(op, shapes, dtypes, platform, static)
+
+    def resolutions(self) -> dict[tuple[str, str], int]:
+        """How often each ``(op, impl)`` was chosen by :meth:`dispatch`.
+
+        Counted when ``dispatch`` runs, which inside ``jax.jit`` is trace
+        time: a cached executable adds nothing. So the record says which
+        impl each traced program holds — a kernel, or the ``ref`` oracle it
+        negotiated down to."""
+        return dict(self._resolved)
+
+    def clear_resolutions(self) -> None:
+        self._resolved.clear()
 
     def describe(self) -> str:
         lines = []
@@ -414,6 +432,7 @@ class OpRegistry:
         be = resolve_backend(backend)
         req = self.request(op, *args, **kwargs)
         impl = self.select(op, req, be)
+        self._resolved[(op, impl.name)] += 1
         # typo'd kwargs must fail loudly, as the pre-registry jitted ops did;
         # only *tuning-table defaults* are filtered per-impl below (the ref
         # oracle legitimately ignores the kernel's tile sizes)

@@ -10,7 +10,7 @@ never per-scalar:
   ``(bs_k, bs_n)`` weight tiles. The kernel keeps the dense gemm schedule
   (grid ``(M/bm, N/bn, K/bk)``, K innermost, VMEM fp32 accumulator) and
   skips the MXU issue for masked tiles via ``pl.when`` — zero blocks cost
-  a (1, 1) SMEM-sized mask read instead of a (bk, bn) FLOP tile.
+  one scalar-prefetched SMEM mask read instead of a (bk, bn) FLOP tile.
 * **2:4 fine-grained** — every group of 4 consecutive K elements keeps its
   2 largest-magnitude values. Storage is ``(K/2, N)`` values + ``(K/2, N)``
   int8 column-local indices; the kernel densifies in-tile with an
@@ -104,16 +104,18 @@ def _epilogue(out, scale, act, out_dtype):
 # --------------------------------------------------------------------------
 # block-sparse kernel
 # --------------------------------------------------------------------------
-def _bs_kernel(x_ref, w_ref, m_ref, o_ref, acc_ref, *, n_k: int,
-               scale: float, act: str | None, out_dtype):
+def _bs_kernel(m_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int, nb: int,
+               rk: int, rn: int, scale: float, act: str | None, out_dtype):
+    j = pl.program_id(1)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # the (1, 1) mask tile gates the whole MXU issue for this K step
-    @pl.when(m_ref[0, 0] != 0)
+    # the flattened block mask rides in SMEM (scalar prefetch); one entry
+    # gates the whole MXU issue for this K step
+    @pl.when(m_ref[(k // rk) * nb + j // rn] != 0)
     def _accum():
         acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32),
                                 w_ref[...].astype(jnp.float32),
@@ -145,21 +147,25 @@ def gemm_sparse(x, w, mask, *, scale: float = 1.0, act: str | None = None,
     grid = (M // block_m, N // block_n, n_k)
     rk, rn = bs_k // block_k, bs_n // block_n     # kernel tiles per block
 
-    kernel = functools.partial(_bs_kernel, n_k=n_k, scale=scale, act=act,
-                               out_dtype=out_dtype)
-    return pl.pallas_call(
-        kernel,
+    kernel = functools.partial(_bs_kernel, n_k=n_k, nb=nb, rk=rk, rn=rn,
+                               scale=scale, act=act, out_dtype=out_dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                       # flattened block mask
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (k // rk, j // rn)),
+            pl.BlockSpec((block_m, block_k), lambda i, j, k, m: (i, k)),
+            pl.BlockSpec((block_k, block_n), lambda i, j, k, m: (k, j)),
         ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda i, j, k, m: (i, j)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         interpret=interpret,
-    )(x, w, mask.astype(jnp.int32))
+    )(mask.astype(jnp.int32).reshape(-1), x, w)
 
 
 # --------------------------------------------------------------------------

@@ -16,9 +16,10 @@ VMEM, never in HBM.
 
 Scales arrive pre-gathered per K-tile: the wrapper (ops.py) turns the
 ``(n_blocks, N)`` per-block scales into ``(n_k_tiles, N)`` rows — one row
-per K grid step — so the kernel reads a ``(1, bn)`` scale tile with a plain
-``(k, j)`` index map and never straddles a quant-block boundary (the
-wrapper picks ``block_k`` to divide the quant block).
+per K grid step — so a scale tile never straddles a quant-block boundary
+(the wrapper picks ``block_k`` to divide the quant block). The kernel views
+the rows as ``(n_k_tiles, 1, N)`` and reads ``(1, 1, bn)`` tiles: their last
+two dims span the array's ``(1, N)``, as the TPU lowering requires.
 """
 from __future__ import annotations
 
@@ -31,15 +32,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _dequant_tile(q_ref, s_ref, pack: int):
-    """(bk/pack, bn) storage tile -> (bk, bn) fp32 weight tile."""
+    """(bk/pack, bn) storage tile, (1, 1, bn) scale tile -> (bk, bn) fp32
+    weight tile."""
     q = q_ref[...]
     if pack == 2:
-        # sign-extending nibble unpack: lo via shift-up/arith-shift-down,
-        # hi via arithmetic shift; interleave restores the logical K order
-        lo = (q << 4).astype(jnp.int8) >> 4
+        # sign-extending nibble unpack in int32 (the TPU's vector unit has
+        # no int8 shifts): lo via shift-up/arith-shift-down, hi via
+        # arithmetic shift; interleave restores the logical K order
+        q = q.astype(jnp.int32)
+        lo = (q << 28) >> 28
         hi = q >> 4
         q = jnp.stack([lo, hi], axis=1).reshape(q.shape[0] * 2, q.shape[1])
-    return q.astype(jnp.float32) * s_ref[...].astype(jnp.float32)
+    return q.astype(jnp.float32) * s_ref[0].astype(jnp.float32)
 
 
 def _wq_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, n_k: int, scale: float,
@@ -111,6 +115,10 @@ def gemm_wq(x, qw, tile_scales, *, bias=None, scale: float = 1.0,
     assert tile_scales.shape == (n_k, N), (tile_scales.shape, n_k, N)
     grid = (M // block_m, N // block_n, n_k)
     bkq = block_k // pack          # storage rows per weight tile
+    # scale rows ride as (n_k, 1, N) so the block's last two dims (1, bn)
+    # match the array's (1, N) row, as the TPU lowering requires
+    tile_scales = tile_scales.reshape(n_k, 1, N)
+    scale_spec = pl.BlockSpec((1, 1, block_n), lambda i, j, k: (k, 0, j))
 
     if bias is None:
         kernel = functools.partial(_wq_kernel, n_k=n_k, scale=scale, act=act,
@@ -118,7 +126,7 @@ def gemm_wq(x, qw, tile_scales, *, bias=None, scale: float = 1.0,
         in_specs = [
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
             pl.BlockSpec((bkq, block_n), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (k, j)),
+            scale_spec,
         ]
         args = (x, qw, tile_scales)
     else:
@@ -127,7 +135,7 @@ def gemm_wq(x, qw, tile_scales, *, bias=None, scale: float = 1.0,
         in_specs = [
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
             pl.BlockSpec((bkq, block_n), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (k, j)),
+            scale_spec,
             pl.BlockSpec((1, block_n), lambda i, j, k: (0, j)),
         ]
         args = (x, qw, tile_scales, bias.reshape(1, N))

@@ -537,9 +537,18 @@ def gather_rows(table, idx):
     return registry.dispatch("gather_rows", table, idx)
 
 
+def _packed_gather_supports(req: OpRequest) -> bool:
+    # The TPU compiler refuses the kernel's dynamic one-row reads from the
+    # staged window: "Mosaic failed to compile TPU kernel: cannot statically
+    # prove that index in dimension 0 is a multiple of 8" (bf16 tables), and
+    # the window, which spans the whole table, overflows VMEM at real
+    # widths. On the chip the request therefore runs as the XLA gather.
+    return req.platform != "tpu" and _gather_supports(req)
+
+
 @registry.register("packed_gather_rows", "pallas",
                    backends=("pallas", "interpret"),
-                   supports=_gather_supports, priority=10, pass_interpret=True)
+                   supports=_packed_gather_supports, priority=10, pass_interpret=True)
 @partial(jax.jit, static_argnames=("pack", "sort", "interpret"))
 def _packed_gather_kernel(table, idx, *, pack: int = 8, sort: bool = True,
                           interpret: bool = False):

@@ -27,22 +27,25 @@ def gather_rows(table, idx, *, interpret: bool = False):
     """out[i] = table[idx[i]]  — one row per grid step, index-driven DMA.
 
     table: (N, D); idx: (M,) int32. The narrow-stream baseline (8 B–wide
-    requests in the paper; one D-row here).
+    requests in the paper; one D-row here). Rows ride as ``(N, 1, D)`` so a
+    ``(1, 1, D)`` block spans the array's last two dims, as the TPU lowering
+    requires of a one-row block.
     """
     N, D = table.shape
     M = idx.shape[0]
+    row = pl.BlockSpec((1, 1, D), lambda i, idx_ref: (idx_ref[i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(M,),
-        in_specs=[pl.BlockSpec((1, D), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, D), lambda i, idx_ref: (i, 0)),
+        in_specs=[row],
+        out_specs=pl.BlockSpec((1, 1, D), lambda i, idx_ref: (i, 0, 0)),
     )
     return pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, D), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((M, 1, D), table.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), table)
+    )(idx.astype(jnp.int32), table.reshape(N, 1, D)).reshape(M, D)
 
 
 def _packed_kernel(idx_ref, table_ref, o_ref, *, pack: int, window: int):

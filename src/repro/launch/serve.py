@@ -156,8 +156,11 @@ def main(argv=None):
     import numpy as np
 
     from repro.configs import get_arch, reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import init as model_init
     from repro.serve import Request, ServeEngine
+
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -19,6 +19,7 @@ import jax
 
 from repro.configs import get_arch, get_shape, reduced, strategy
 from repro.configs.base import ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.optimizers import get_optimizer
 from repro.optim.schedules import get_schedule
 from repro.train.trainer import FaultInjector, Trainer, TrainerConfig
@@ -47,6 +48,7 @@ def main(argv=None):
     ap.add_argument("--inject-fault-at", type=int, default=-1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

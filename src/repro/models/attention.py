@@ -463,6 +463,12 @@ def attention_verify(p: Params, cfg: ModelConfig, x, cache: dict, *,
             "v_scale": cache["v_scale"].at[blk_w, rows].set(
                 vsc.astype(cache["v_scale"].dtype), mode="drop"),
         }
+        # the chunk reads its own rows as stored, as decode and a later
+        # prefix hit read them
+        k = dequantize_kv(kq.astype(pool_k.dtype),
+                          ksc.astype(cache["k_scale"].dtype), compute_dtype)
+        v = dequantize_kv(vq.astype(pool_v.dtype),
+                          vsc.astype(cache["v_scale"].dtype), compute_dtype)
         k_old = dequantize_kv(pool_k[block_tables],
                               cache["k_scale"][block_tables],
                               compute_dtype).reshape(B, n_pages * page, K, hd)
@@ -573,6 +579,14 @@ def attention_extend(p: Params, cfg: ModelConfig, x, cache: dict, *,
                 "v_scale": cache["v_scale"].at[blk_w, rows].set(
                     vsc.astype(cache["v_scale"].dtype), mode="drop"),
             }
+            # the chunk reads its own rows as stored, as decode and a later
+            # prefix hit read them
+            k = dequantize_kv(kq.astype(pool_k.dtype),
+                              ksc.astype(cache["k_scale"].dtype),
+                              compute_dtype)[None]
+            v = dequantize_kv(vq.astype(pool_v.dtype),
+                              vsc.astype(cache["v_scale"].dtype),
+                              compute_dtype)[None]
             k_old = dequantize_kv(pool_k[table_row],
                                   cache["k_scale"][table_row],
                                   compute_dtype).reshape(

@@ -338,11 +338,11 @@ def moe_forward_ep(p: Params, cfg: ModelConfig, x, *, compute_dtype, part):
             y = jax.lax.psum(y, "model")
         return y, aux_g
 
-    from repro.core.collectives import shard_map_compat
-    y, aux_g = shard_map_compat(
+    y, aux_g = jax.shard_map(
         body, mesh=mesh,
         in_specs=(bspec, P(None, None), wspec, wspec, wspec),
-        out_specs=(bspec, P(bspec[0] if G > 1 else None)))(xc, router, wg, wu, wd)
+        out_specs=(bspec, P(bspec[0] if G > 1 else None)),
+        check_vma=False)(xc, router, wg, wu, wd)
     return y.reshape(B, S, d).astype(x.dtype), aux_g.mean()
 
 

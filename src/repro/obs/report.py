@@ -13,10 +13,17 @@ the analytic cost model in ``core/roofline.py`` / ``core/memfloor.py``:
 * **HBM bandwidth utilization** — the per-step decode *floor* bytes from
   ``memfloor.hbm_bytes_floor`` (weights replicated in serve mode, KV cache
   sharded ``kv_shard``-way) replayed at the measured step rate, over
-  ``CHIP.hbm_bw``. This is a lower bound on true traffic, so the reported
+  the chip's ``hbm_bw``. This is a lower bound on true traffic, so the reported
   fraction is "what the floor model says we must have moved".
 * **D2D bandwidth utilization** — ``memfloor.d2d_bytes_serve_decode`` at the
-  measured average batch, over ``CHIP.ici_link_bw`` (zero off-shard).
+  measured average batch, over the chip's ``ici_link_bw`` (zero
+  off-shard).
+
+The three shares are device metrics: they are reported only when the run's
+device kind (``jax.devices()[0].device_kind``) and the config's compute dtype
+are in the peak table (``core/topology.py:PEAKS``). Anywhere else, a CPU run
+included, they read ``"not measured"`` rather than dividing host time by a
+TPU peak.
 
 ``utilization_report(engine)`` reads one engine; ``windows_from_trace``
 re-derives a per-window series from a :class:`~repro.obs.trace.Tracer`'s
@@ -26,8 +33,23 @@ from __future__ import annotations
 
 from typing import Any
 
-__all__ = ["decode_utilization", "utilization_report", "windows_from_trace",
-           "write_metrics_json"]
+__all__ = ["NOT_MEASURED", "decode_utilization", "utilization_report",
+           "windows_from_trace", "write_metrics_json"]
+
+NOT_MEASURED = "not measured"
+
+
+def _chip(device_kind: str | None, dtype: str):
+    """(chip spec, peak op/s) for a device kind in the peak table, or None."""
+    from repro.core.topology import PEAKS
+
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    spec = PEAKS.get(device_kind)
+    if spec is None or str(dtype) not in dict(spec.peak_ops):
+        return None
+    return spec, spec.peak(dtype)
 
 
 def _serve_decode_floor(cfg, *, batch: float, context: float,
@@ -61,25 +83,30 @@ def _serve_decode_floor(cfg, *, batch: float, context: float,
 
 def decode_utilization(cfg, *, tokens: float, steps: float, wall_s: float,
                        batch_sum: float, kv_row_sum: float,
-                       kv_shard: int = 1) -> dict:
+                       kv_shard: int = 1,
+                       device_kind: str | None = None) -> dict:
     """Join one measured decode window against the analytic model.
 
     ``tokens``: tokens committed in the window (spec-decode commits count);
     ``steps``: decode dispatches; ``wall_s``: measured wall seconds;
     ``batch_sum``: sum over dispatches of active decode slots;
-    ``kv_row_sum``: sum over dispatches of context rows attended.
+    ``kv_row_sum``: sum over dispatches of context rows attended;
+    ``device_kind``: the device that ran the window (default: this
+    process's first device). MFU and the bandwidth shares need it in the
+    peak table, else they read ``"not measured"``.
     """
     from repro.core.memfloor import d2d_bytes_serve_decode
-    from repro.core.topology import CHIP, dtype_peak_flops
 
     n_dev = max(int(kv_shard), 1)
+    chip = _chip(device_kind, cfg.dtype)
     if steps <= 0 or wall_s <= 0:
+        share = 0.0 if chip else NOT_MEASURED
         return {"tokens": int(tokens), "steps": int(steps),
                 "wall_s": wall_s, "tok_per_s": 0.0, "avg_batch": 0.0,
                 "avg_context": 0.0, "flops_per_token": 0.0,
-                "achieved_tflops": 0.0, "mfu": 0.0,
-                "hbm_floor_bytes_per_step_dev": 0.0, "hbm_util": 0.0,
-                "d2d_bytes_per_step_dev": 0.0, "d2d_util": 0.0,
+                "achieved_tflops": 0.0, "mfu": share,
+                "hbm_floor_bytes_per_step_dev": 0.0, "hbm_util": share,
+                "d2d_bytes_per_step_dev": 0.0, "d2d_util": share,
                 "devices": n_dev}
 
     avg_batch = batch_sum / steps
@@ -88,7 +115,6 @@ def decode_utilization(cfg, *, tokens: float, steps: float, wall_s: float,
     pc = cfg.param_count()
     flops_per_token = 2.0 * (pc["nonembed_active"] + pc["embedding"])
     achieved = flops_per_token * tokens / wall_s
-    peak = dtype_peak_flops(cfg.dtype) * n_dev
 
     floor = _serve_decode_floor(cfg, batch=avg_batch, context=avg_context,
                                 kv_shard=n_dev)
@@ -96,6 +122,13 @@ def decode_utilization(cfg, *, tokens: float, steps: float, wall_s: float,
     d2d = d2d_bytes_serve_decode(cfg, max(int(round(avg_batch)), 1), n_dev)
     d2d_rate = d2d["total"] * steps / wall_s
 
+    if chip:
+        spec, peak = chip
+        mfu = round(achieved / (peak * n_dev), 6)
+        hbm_util = round(hbm_rate / spec.hbm_bw, 6)
+        d2d_util = round(d2d_rate / spec.ici_link_bw, 6)
+    else:
+        mfu = hbm_util = d2d_util = NOT_MEASURED
     return {
         "tokens": int(tokens),
         "steps": int(steps),
@@ -105,11 +138,11 @@ def decode_utilization(cfg, *, tokens: float, steps: float, wall_s: float,
         "avg_context": round(avg_context, 2),
         "flops_per_token": flops_per_token,
         "achieved_tflops": round(achieved / 1e12, 6),
-        "mfu": round(achieved / peak, 6),
+        "mfu": mfu,
         "hbm_floor_bytes_per_step_dev": round(floor["total"], 1),
-        "hbm_util": round(hbm_rate / CHIP.hbm_bw, 6),
+        "hbm_util": hbm_util,
         "d2d_bytes_per_step_dev": round(d2d["total"], 1),
-        "d2d_util": round(d2d_rate / CHIP.ici_link_bw, 6),
+        "d2d_util": d2d_util,
         "devices": n_dev,
     }
 

@@ -303,6 +303,14 @@ def _sample(logits, temps, top_k, top_p, keys):
     return sample_tokens(logits, temps, top_k, top_p, keys)
 
 
+def _h2d(x):
+    """A device array of host state that owns its bytes. On the CPU
+    ``jnp.asarray`` and ``jnp.array`` may alias a NumPy buffer, and the
+    engine mutates its slot arrays and block tables in place while a step
+    it dispatched asynchronously may not have read them yet."""
+    return jnp.asarray(np.array(x, copy=True))
+
+
 @dataclass
 class _Pending:
     """One dispatched-but-unsynced decode step (overlap double-buffer)."""
@@ -672,7 +680,7 @@ class ServeEngine:
         return contextlib.nullcontext()
 
     def _tables(self):
-        return jnp.asarray(self.block_tables) if self.paged else None
+        return _h2d(self.block_tables) if self.paged else None
 
     def _pin(self, cache):
         """Pin a jitted graph's output cache to the serve shardings so the
@@ -1160,7 +1168,7 @@ class ServeEngine:
         if req.seed is not None:
             return np.asarray(jax.random.PRNGKey(int(req.seed)), np.uint32)
         return np.asarray(
-            jax.random.fold_in(jnp.asarray(self._base_key),
+            jax.random.fold_in(_h2d(self._base_key),
                                np.uint32(req.uid & 0xFFFFFFFF)), np.uint32)
 
     def _place(self, entry, slot: int, legacy: bool) -> None:
@@ -1246,7 +1254,7 @@ class ServeEngine:
             # child streams branch off the parent key through a fork tag:
             # child i is reproducible given (request seed, i)
             self._slot_key[cs] = np.asarray(jax.random.fold_in(
-                jax.random.fold_in(jnp.asarray(self._slot_key[slot]),
+                jax.random.fold_in(_h2d(self._slot_key[slot]),
                                    np.uint32(_P_FORK)),
                 np.uint32(i + 1)), np.uint32)
             self._slot_ctr[cs] = 0
@@ -1321,13 +1329,13 @@ class ServeEngine:
         self.cache = self._commit_fn(self.cache, slot_cache, np.int32(slot),
                                      self._tables())
         kk = _fold_keys(
-            jnp.asarray(self._slot_key[slot][None]),
+            _h2d(self._slot_key[slot][None]),
             jnp.asarray([self._slot_ctr[slot] & 0x7FFFFFFF], jnp.uint32),
             _P_SAMPLE)
         first = int(_sample(logits,
                             jnp.asarray([req.temperature], jnp.float32),
-                            jnp.asarray(self.slot_topk[slot][None]),
-                            jnp.asarray(self.slot_topp[slot][None]),
+                            _h2d(self.slot_topk[slot][None]),
+                            _h2d(self.slot_topp[slot][None]),
                             kk)[0])
         self._slot_ctr[slot] += 1
         self.phase[slot] = DECODE
@@ -1360,7 +1368,7 @@ class ServeEngine:
                         self._tables(), np.float32(req.temperature),
                         np.int32(self.slot_topk[slot]),
                         np.float32(self.slot_topp[slot]),
-                        jnp.asarray(self._slot_key[slot]),
+                        _h2d(self._slot_key[slot]),
                         np.uint32(self._slot_ctr[slot] & 0x7FFFFFFF),
                         np.int32(self._first_new[slot]))
                 self._slot_ctr[slot] += 1
@@ -1605,12 +1613,12 @@ class ServeEngine:
         if not mask.any():
             return None
         active = jnp.asarray(mask)
-        temps = jnp.asarray(self.slot_temp)
-        topk = jnp.asarray(self.slot_topk)
-        topp = jnp.asarray(self.slot_topp)
-        keys = jnp.asarray(self._slot_key)
+        temps = _h2d(self.slot_temp)
+        topk = _h2d(self.slot_topk)
+        topp = _h2d(self.slot_topp)
+        keys = _h2d(self._slot_key)
         ctrs = jnp.asarray((self._slot_ctr & 0x7FFFFFFF).astype(np.uint32))
-        pos = jnp.asarray(self.slot_pos)
+        pos = _h2d(self.slot_pos)
         n_valid = jnp.asarray(np.where(mask, k_eff + 1, 1).astype(np.int32))
         with self._kernel_scope():
             dtoks, dprobs = self.draft.propose(
@@ -1740,10 +1748,10 @@ class ServeEngine:
         with self._kernel_scope():
             ids, self.cache = self._decode_fn(
                 self.params, self.cache, feed,
-                jnp.asarray(self.slot_pos), jnp.asarray(dec), self._tables(),
-                jnp.asarray(self.slot_temp),
-                jnp.asarray(self.slot_topk), jnp.asarray(self.slot_topp),
-                jnp.asarray(self._slot_key),
+                _h2d(self.slot_pos), jnp.asarray(dec), self._tables(),
+                _h2d(self.slot_temp),
+                _h2d(self.slot_topk), _h2d(self.slot_topp),
+                _h2d(self._slot_key),
                 jnp.asarray((self._slot_ctr & 0x7FFFFFFF).astype(np.uint32)))
         self._slot_ctr[dec] += 1
         self.stats["decode_steps"] += 1

@@ -1,8 +1,6 @@
-"""Hypothesis shim: the real library when installed, a skip-only fallback
-otherwise (minimal containers ship without a hypothesis wheel; property tests
-skip rather than killing collection for the whole suite).
+"""Hypothesis settings profiles shared by the property tests.
 
-With the real library, two settings profiles are registered:
+Two profiles are registered:
 
 * ``ci`` — fixed-seed/deterministic (``derandomize=True``), fewer examples:
   the profile the CI ``pytest -m property`` step runs, so a red property
@@ -17,38 +15,14 @@ from __future__ import annotations
 
 import os
 
-try:
-    from hypothesis import HealthCheck, given, settings
-    from hypothesis import strategies as st
+from hypothesis import HealthCheck, given, settings
+from hypothesis import strategies as st
 
-    HAVE_HYPOTHESIS = True
-    settings.register_profile(
-        "ci", max_examples=25, derandomize=True, deadline=None,
-        suppress_health_check=[HealthCheck.too_slow])
-    settings.register_profile("dev", max_examples=75, deadline=None)
-    settings.load_profile(os.environ.get(
-        "HYPOTHESIS_PROFILE", "ci" if os.environ.get("CI") else "dev"))
-except ImportError:  # pragma: no cover - exercised only without hypothesis
-    import pytest
+settings.register_profile(
+    "ci", max_examples=25, derandomize=True, deadline=None,
+    suppress_health_check=[HealthCheck.too_slow])
+settings.register_profile("dev", max_examples=75, deadline=None)
+settings.load_profile(os.environ.get(
+    "HYPOTHESIS_PROFILE", "ci" if os.environ.get("CI") else "dev"))
 
-    HAVE_HYPOTHESIS = False
-
-    def settings(*_a, **_k):
-        return lambda f: f
-
-    def given(*_a, **_k):
-        def deco(f):
-            def _skipped():
-                pytest.skip("hypothesis not installed")
-            _skipped.__name__ = f.__name__
-            _skipped.__doc__ = f.__doc__
-            return _skipped
-        return deco
-
-    class _Strategies:
-        def __getattr__(self, name):
-            return lambda *a, **k: None
-
-    st = _Strategies()
-
-__all__ = ["HAVE_HYPOTHESIS", "given", "settings", "st"]
+__all__ = ["given", "settings", "st"]

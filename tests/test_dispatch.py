@@ -151,6 +151,63 @@ def test_pallas_backend_off_tpu_negotiates_down():
                                rtol=1e-6, atol=1e-6)
 
 
+def test_resolution_record_shows_ref_off_tpu():
+    """The registry records which impl each dispatch chose: pinning
+    'pallas' on CPU runs the oracle, and the record says so."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("compiled pallas exists here")
+    x = _rand((48, 40))
+    w = _rand((40, 56), seed=1)
+    registry.clear_resolutions()
+    with use_backend("pallas"):
+        ops.gemm(x, w)
+        ops.gemm(x, w)
+    assert registry.resolutions() == {("gemm", "ref"): 2}
+    with use_backend("interpret"):
+        ops.gemm(x, w)
+    assert registry.resolutions()[("gemm", "pallas")] == 1
+    registry.clear_resolutions()
+    assert registry.resolutions() == {}
+
+
+def test_auto_scope_selects_kernels_only_on_tpu(monkeypatch):
+    """A ``use_backend("auto")`` scope opens the model's kernel path where
+    the platform resolves auto to the Pallas kernels, and nowhere else."""
+    from repro.kernels import dispatch
+
+    with use_backend("auto"):
+        assert not dispatch.kernel_scope_active()
+        monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+        assert dispatch.kernel_scope_active()
+
+
+def test_resolution_record_counts_at_trace_time():
+    """Inside jit the record counts traces, not executions."""
+    x = _rand((16, 16))
+    registry.clear_resolutions()
+    f = jax.jit(lambda a: ops.gemm(a, a))
+    with use_backend("ref"):
+        f(x)
+        f(x)
+    assert registry.resolutions() == {("gemm", "ref"): 1}
+    registry.clear_resolutions()
+
+
+def test_packed_gather_declines_on_tpu():
+    """The TPU compiler refuses the packed-gather kernel (dynamic one-row
+    reads of a bf16 window; a whole-table VMEM window), so a TPU request
+    negotiates down to the oracle while CPU interpret keeps the kernel."""
+    from repro.kernels.dispatch import Backend, OpRequest
+
+    shapes, dtypes = ((4096, 1024), (64,)), ("bfloat16", "int32")
+    tpu = OpRequest("packed_gather_rows", shapes, dtypes, "tpu")
+    cpu = OpRequest("packed_gather_rows", shapes, dtypes, "cpu")
+    assert registry.select("packed_gather_rows", tpu,
+                           Backend("pallas", "tpu")).name == "ref"
+    assert registry.select("packed_gather_rows", cpu,
+                           Backend("interpret", "cpu")).name == "pallas"
+
+
 def test_unknown_kwargs_raise():
     """Typo'd kwargs fail loudly, as the pre-registry jitted ops did."""
     x = _rand((8, 8))

@@ -233,18 +233,37 @@ def _cfg():
         d_ff=128, vocab_size=256, dtype="float32")
 
 
+V5E = "TPU v5 lite"
+
+
 def test_decode_utilization_zero_window_is_safe():
-    row = decode_utilization(_cfg(), tokens=0, steps=0, wall_s=0.0,
-                             batch_sum=0, kv_row_sum=0)
+    row = decode_utilization(_cfg().replace(dtype="bfloat16"), tokens=0,
+                             steps=0, wall_s=0.0, batch_sum=0, kv_row_sum=0,
+                             device_kind=V5E)
     assert row["mfu"] == 0.0 and row["hbm_util"] == 0.0
     assert row["d2d_util"] == 0.0 and row["tok_per_s"] == 0.0
 
 
+@pytest.mark.parametrize("kind,dtype", [("cpu", "bfloat16"),
+                                        (V5E, "float32"), (None, "bfloat16")])
+def test_decode_utilization_not_measured_off_table(kind, dtype):
+    """A device kind or dtype outside the peak table (this CPU process
+    included) gets no MFU or bandwidth share, never host time over a TPU
+    peak."""
+    row = decode_utilization(_cfg().replace(dtype=dtype), tokens=64,
+                             steps=16, wall_s=1e-3, batch_sum=64,
+                             kv_row_sum=64 * 40, device_kind=kind)
+    for key in ("mfu", "hbm_util", "d2d_util"):
+        assert row[key] == "not measured"
+    assert row["tok_per_s"] == pytest.approx(64000.0)
+
+
 def test_decode_utilization_measured_window():
-    cfg = _cfg()
+    cfg = _cfg().replace(dtype="bfloat16")
     # a fast window: the tiny config's MFU must survive 6-decimal rounding
     row = decode_utilization(cfg, tokens=64, steps=16, wall_s=1e-3,
-                             batch_sum=64, kv_row_sum=64 * 40, kv_shard=2)
+                             batch_sum=64, kv_row_sum=64 * 40, kv_shard=2,
+                             device_kind=V5E)
     pc = cfg.param_count()
     per_tok = 2.0 * (pc["nonembed_active"] + pc["embedding"])
     assert row["flops_per_token"] == per_tok
@@ -255,7 +274,8 @@ def test_decode_utilization_measured_window():
     assert row["d2d_util"] > 0 and row["devices"] == 2
     # single-device run moves no D2D traffic
     solo = decode_utilization(cfg, tokens=64, steps=16, wall_s=1e-3,
-                              batch_sum=64, kv_row_sum=64 * 40, kv_shard=1)
+                              batch_sum=64, kv_row_sum=64 * 40, kv_shard=1,
+                              device_kind=V5E)
     assert solo["d2d_util"] == 0.0
 
 

@@ -6,7 +6,8 @@ import pytest
 
 from repro.core.roofline import (_shape_bytes, analyze_costs, model_flops,
                                  parse_collectives)
-from repro.core.topology import CHIP, dtype_peak_flops, roofline_time
+from repro.core.topology import (CHIP, chip_spec, dtype_peak_flops,
+                                  roofline_time)
 
 HLO = """
 ENTRY main {
@@ -117,9 +118,27 @@ def test_moe_uses_active_params():
 
 
 def test_dtype_peaks():
-    assert dtype_peak_flops("bfloat16") == CHIP.peak_bf16_flops
-    assert dtype_peak_flops("float32") == pytest.approx(98.5e12)
-    assert dtype_peak_flops("float8_e4m3fn") == 2 * CHIP.peak_bf16_flops
+    """The published v5e peaks: bf16 and int8 only; halving precision
+    doubles the MXU peak, and there is no fp8 MXU to borrow a peak for."""
+    assert dtype_peak_flops("bfloat16") == CHIP.peak_bf16_flops == 197e12
+    assert dtype_peak_flops("int8") == 393e12
+    for dtype in ("float32", "float8_e4m3fn"):
+        with pytest.raises(KeyError, match="no published peak"):
+            dtype_peak_flops(dtype)
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v6 lite"])
+def test_peak_table_unknown_device_raises(kind):
+    with pytest.raises(KeyError, match="no peak table entry"):
+        chip_spec(kind)
+    with pytest.raises(KeyError):
+        dtype_peak_flops("bfloat16", kind)
+
+
+def test_peak_table_keyed_by_device_kind():
+    spec = chip_spec("TPU v5 lite")
+    assert spec is CHIP
+    assert spec.peak("bfloat16") == 197e12 and spec.hbm_bw == 819e9
 
 
 def test_roofline_time_formulas():

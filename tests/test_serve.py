@@ -121,6 +121,18 @@ def test_overflow_rejected_gracefully(setup):
     assert engine.stats["rejected"] == 1
 
 
+def test_host_upload_owns_its_bytes():
+    """The engine mutates its slot arrays right after dispatching a step.
+    An upload that still reads the NumPy buffer (``jnp.asarray`` on the CPU
+    transfers asynchronously from it) would pick up the later values."""
+    from repro.serve.engine import _h2d
+    for _ in range(5):
+        host = np.zeros(1 << 22, np.float32)
+        dev = _h2d(host)
+        host[:] = 1.0
+        assert float(dev.sum()) == 0.0
+
+
 def test_prefill_jit_cache_reused(setup):
     cfg, params = setup
     rng = np.random.default_rng(3)

@@ -5,13 +5,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_arch, get_shape, strategy
-from repro.core.sharding import Partitioner, abstract_mesh
+from repro.core.sharding import Partitioner
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
-MESH3 = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _part(arch="deepseek-7b", strat="ramora", shape="train_4k", mesh=MESH,
