@@ -6,8 +6,8 @@ and the utilization methodology.
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                Snapshot, StatsView)
 from repro.obs.report import (decode_utilization, utilization_report,
-                              windows_from_trace, write_metrics_json)
-from repro.obs.trace import (NULL_TRACER, NullTracer, Tracer,
+                              write_metrics_json)
+from repro.obs.trace import (NULL_TRACER, STEP_SPANS, NullTracer, Tracer,
                              validate_chrome_trace)
 
 __all__ = [
@@ -17,12 +17,12 @@ __all__ = [
     "MetricsRegistry",
     "NULL_TRACER",
     "NullTracer",
+    "STEP_SPANS",
     "Snapshot",
     "StatsView",
     "Tracer",
     "decode_utilization",
     "utilization_report",
     "validate_chrome_trace",
-    "windows_from_trace",
     "write_metrics_json",
 ]

@@ -25,16 +25,15 @@ are in the peak table (``core/topology.py:PEAKS``). Anywhere else, a CPU run
 included, they read ``"not measured"`` rather than dividing host time by a
 TPU peak.
 
-``utilization_report(engine)`` reads one engine; ``windows_from_trace``
-re-derives a per-window series from a :class:`~repro.obs.trace.Tracer`'s
-dispatch/sync instants when tracing was enabled.
+``utilization_report(engine)`` reads one engine. Time within a step is
+read from the ``serve.*`` profiler spans (``repro.obs.trace.STEP_SPANS``).
 """
 from __future__ import annotations
 
 from typing import Any
 
 __all__ = ["NOT_MEASURED", "decode_utilization", "utilization_report",
-           "windows_from_trace", "write_metrics_json"]
+           "write_metrics_json"]
 
 NOT_MEASURED = "not measured"
 
@@ -161,45 +160,6 @@ def utilization_report(engine) -> dict:
         kv_row_sum=snap.counters.get("decode_window_kv_rows", 0.0),
         kv_shard=getattr(engine, "_kv_shard", 1),
     )
-
-
-def windows_from_trace(trace, cfg, *, kv_shard: int = 1,
-                       window_steps: int = 32) -> list[dict]:
-    """Per-window utilization series from a tracer's decode instants.
-
-    Groups consecutive ``dispatch`` events (which carry ``n`` active slots
-    and ``kv`` context rows) into windows of ``window_steps`` dispatches;
-    tokens come from the ``sync`` / ``spec_commit`` instants that land
-    inside the window's time range. Requires tracing to have been enabled
-    for the run — returns ``[]`` on an empty or disabled trace.
-    """
-    evs = trace.events()
-    dispatches = [e for e in evs if e.name == "dispatch"]
-    if not dispatches:
-        return []
-    emits = [(e.ts, dict(e.args)) for e in evs
-             if e.name in ("sync", "spec_commit")]
-    out = []
-    for w0 in range(0, len(dispatches), window_steps):
-        group = dispatches[w0:w0 + window_steps]
-        t_lo = group[0].ts
-        t_hi = (dispatches[w0 + window_steps].ts
-                if w0 + window_steps < len(dispatches)
-                else max(e.ts for e in evs))
-        args = [dict(e.args) for e in group]
-        tokens = sum(a.get("tokens", a.get("accepted", 0))
-                     for ts, a in emits if t_lo <= ts <= t_hi)
-        row = decode_utilization(
-            cfg,
-            tokens=tokens,
-            steps=len(group),
-            wall_s=max(t_hi - t_lo, 1e-9),
-            batch_sum=sum(a.get("n", 0) for a in args),
-            kv_row_sum=sum(a.get("kv", 0) for a in args),
-            kv_shard=kv_shard)
-        row["window"] = w0 // window_steps
-        out.append(row)
-    return out
 
 
 def write_metrics_json(path: str, *, suite: str, snapshot,

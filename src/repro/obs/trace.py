@@ -11,6 +11,13 @@ Pay-for-what-you-use: a disabled tracer is :data:`NULL_TRACER`, whose
 methods are empty — call sites invoke it unconditionally instead of
 branching on a flag, so the hot path carries no if-forest.
 
+The ring is on ``time.perf_counter``, which no device trace shares. The
+phases of an engine step are therefore not ring events: ``span(name)``, the
+same on every tracer, returns a ``jax.profiler.TraceAnnotation``, which
+lands in a ``jax.profiler`` capture beside the device's ops whenever one is
+recording, whichever tracer the engine holds, and costs one inactive TraceMe
+otherwise. :data:`STEP_SPANS` lists their names.
+
 Export is the Chrome trace-event JSON format (``to_chrome``), loadable in
 Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``: spans become
 async ``b``/``e`` events matched on ``(cat, id, name)`` so preempt/resume
@@ -27,9 +34,12 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "NULL_TRACER",
     "NullTracer",
+    "STEP_SPANS",
     "TraceEvent",
     "Tracer",
     "validate_chrome_trace",
@@ -45,6 +55,19 @@ INSTANTS = (
     "spec_propose", "spec_commit", "spec_rollback", "cow", "fork",
     "finish", "truncate",
 )
+
+#: profiler spans of ``ServeEngine.step``: the step, then its phases, each
+#: a child of ``serve.step`` (see docs/observability.md)
+STEP_SPANS = (
+    "serve.step", "serve.admit", "serve.prefill", "serve.decode.dispatch",
+    "serve.decode.sync", "serve.decode.emit", "serve.account", "serve.spec",
+)
+
+
+def span(name: str, **args: Any) -> TraceAnnotation:
+    """A profiler span for ``with``: recorded, with ``args`` as its stats,
+    while a ``jax.profiler`` trace is active; one inactive TraceMe else."""
+    return TraceAnnotation(name, **args)
 
 
 def _scalar(v: Any) -> Any:
@@ -117,6 +140,8 @@ class Tracer:
 
     def open_spans(self, uid: int) -> tuple[str, ...]:
         return tuple(self._open.get(uid, ()))
+
+    span = staticmethod(span)
 
     # ---- reading / export ------------------------------------------------
     def events(self) -> list[TraceEvent]:
@@ -194,6 +219,8 @@ class NullTracer:
 
     def open_spans(self, uid):
         return ()
+
+    span = staticmethod(span)
 
     def events(self):
         return []

@@ -131,6 +131,7 @@ class Result:
     prefill_s: float = 0.0
     decode_steps: int = 0
     submit_s: float = 0.0                   # perf_counter at submit
+    queued_s: float = 0.0                   # perf_counter at latest enqueue
     token_ts: list[float] = field(default_factory=list)  # one per token
     preempted: int = 0                      # times evicted and requeued
     slo_met: bool | None = None             # None = request had no SLO
@@ -629,6 +630,11 @@ class ServeEngine:
                  "sum over decode dispatches of active slots"),
                 ("decode_window_kv_rows",
                  "sum over decode dispatches of context rows attended"),
+                ("decode_window_kv_reserved_rows",
+                 "sum over decode dispatches of KV rows reserved"),
+                ("queue_wait_s",
+                 "seconds from each request's latest enqueue (submit or "
+                 "requeue) to its placement, summed; one wait per prefills"),
         ):
             self.metrics.counter(name, help_)
         for name, help_ in (
@@ -647,6 +653,9 @@ class ServeEngine:
         self._c_win_tokens = self.metrics.counter("decode_window_tokens")
         self._c_win_batch = self.metrics.counter("decode_window_batch")
         self._c_win_kv = self.metrics.counter("decode_window_kv_rows")
+        self._c_win_kv_res = self.metrics.counter(
+            "decode_window_kv_reserved_rows")
+        self._c_queue_wait = self.metrics.counter("queue_wait_s")
         self._c_finished = self.metrics.counter(
             "finished", "requests finished, by reason", labels=("reason",))
         self.stats = self.metrics.view()
@@ -786,8 +795,9 @@ class ServeEngine:
 
     # ---- streaming ----------------------------------------------------
     def submit(self, req: Request):
-        self.results[req.uid] = Result(uid=req.uid,
-                                       submit_s=time.perf_counter())
+        now = time.perf_counter()
+        self.results[req.uid] = Result(uid=req.uid, submit_s=now,
+                                       queued_s=now)
         self.scheduler.submit(req)
         self.trace.begin("request", req.uid,
                          prompt_tokens=len(req.prompt),
@@ -946,6 +956,7 @@ class ServeEngine:
         if self.draft is not None:
             self.draft.drop(slot)
         res.preempted += 1
+        res.queued_s = time.perf_counter()
         self.stats["preemptions"] += 1
         self.scheduler.requeue(
             dc_replace(req, prompt=new_prompt, max_new_tokens=new_budget),
@@ -982,11 +993,12 @@ class ServeEngine:
         overtakes it until it admits. Impossible requests reject instead of
         crashing the loop; with preemption enabled, a blocked high-priority
         request evicts lower-priority victims first."""
-        guard = 0
-        while self.scheduler and guard <= 4 * self.max_slots + 8:
-            guard += 1
-            if not self._admit_pass():
-                return
+        with self.trace.span("serve.admit"):
+            guard = 0
+            while self.scheduler and guard <= 4 * self.max_slots + 8:
+                guard += 1
+                if not self._admit_pass():
+                    return
 
     def _admit_pass(self) -> bool:
         """One pass over the scheduler order. Returns True when a
@@ -1179,7 +1191,9 @@ class ServeEngine:
         self.scheduler.note_admitted(
             entry, len(req.prompt) + max(1, int(req.n)) * req.max_new_tokens)
         self._admit_hashes.pop(req.uid, None)
-        self._t0[slot] = time.perf_counter()
+        now = time.perf_counter()
+        self._t0[slot] = now
+        self._c_queue_wait.inc(now - self.results[req.uid].queued_s)
         self.slot_uid[slot] = req.uid
         self.slot_temp[slot] = req.temperature
         self.slot_budget[slot] = req.max_new_tokens
@@ -1357,20 +1371,22 @@ class ServeEngine:
             off = int(self._prefill_off[slot])
             if off < len(prompt):
                 t = min(self.prefill_chunk, len(prompt) - off)
-                buf = np.zeros((1, self.prefill_chunk), np.int32)
-                buf[0, :t] = prompt[off:off + t]
-                fn = self._ensure_chunk_fn()
-                self._cow_pages(slot, off, off + t)
-                with self._kernel_scope():
-                    tok, self.cache = fn(
-                        self.params, self.cache, jnp.asarray(buf),
-                        np.int32(off), np.int32(t), np.int32(slot),
-                        self._tables(), np.float32(req.temperature),
-                        np.int32(self.slot_topk[slot]),
-                        np.float32(self.slot_topp[slot]),
-                        _h2d(self._slot_key[slot]),
-                        np.uint32(self._slot_ctr[slot] & 0x7FFFFFFF),
-                        np.int32(self._first_new[slot]))
+                with self.trace.span("serve.prefill",
+                                     uid=int(self.slot_uid[slot]), n=t):
+                    buf = np.zeros((1, self.prefill_chunk), np.int32)
+                    buf[0, :t] = prompt[off:off + t]
+                    fn = self._ensure_chunk_fn()
+                    self._cow_pages(slot, off, off + t)
+                    with self._kernel_scope():
+                        tok, self.cache = fn(
+                            self.params, self.cache, jnp.asarray(buf),
+                            np.int32(off), np.int32(t), np.int32(slot),
+                            self._tables(), np.float32(req.temperature),
+                            np.int32(self.slot_topk[slot]),
+                            np.float32(self.slot_topp[slot]),
+                            _h2d(self._slot_key[slot]),
+                            np.uint32(self._slot_ctr[slot] & 0x7FFFFFFF),
+                            np.int32(self._first_new[slot]))
                 self._slot_ctr[slot] += 1
                 self.stats["prefill_chunks"] += 1
                 self.trace.event("prefill_chunk", int(self.slot_uid[slot]),
@@ -1691,7 +1707,10 @@ class ServeEngine:
         bookkeeping and the next admission run while the device computes,
         at the cost of ids reaching callbacks one step late."""
         t0 = time.perf_counter()
-        skip = self._spec_turn() if self.draft is not None else None
+        skip = None
+        if self.draft is not None:
+            with self.trace.span("serve.spec"):
+                skip = self._spec_turn()
         prev = self._pending
         self._pending = self._dispatch_decode(prev, skip=skip)
         did = (self._pending is not None
@@ -1728,8 +1747,16 @@ class ServeEngine:
             dec &= ~skip
         if not dec.any():
             return None
+        with self.trace.span("serve.decode.dispatch"):
+            return self._dispatch_slots(dec, prev)
+
+    def _dispatch_slots(self, dec: np.ndarray, prev: _Pending | None
+                        ) -> _Pending:
+        """The dispatch of :meth:`_dispatch_decode` for the slots in
+        ``dec`` (at least one)."""
         tokens = np.zeros((self.max_slots, 1), np.int32)
-        for slot in np.nonzero(dec)[0]:
+        stepped = np.nonzero(dec)[0]
+        for slot in stepped:
             if self._emitted(slot) > 0:
                 res = self.results[self.slot_uid[slot]]
                 tokens[slot, 0] = res.tokens[-1]
@@ -1756,11 +1783,15 @@ class ServeEngine:
         self._slot_ctr[dec] += 1
         self.stats["decode_steps"] += 1
         # window accounting at dispatch, before pos advances: rows attended
-        # this step = prior context + the token being written per slot
-        nd = int(dec.sum())
+        # this step = prior context + the token being written per slot;
+        # rows reserved = the slots' granted pages (a dense slot: max_len)
+        nd = len(stepped)
         rows = int(self.slot_pos[dec].sum()) + nd
+        reserved = (sum(len(self.slot_blocks[s]) for s in stepped)
+                    * self.page_size if self.paged else nd * self.max_len)
         self._c_win_batch.inc(nd)
         self._c_win_kv.inc(rows)
+        self._c_win_kv_res.inc(reserved)
         self.trace.event("dispatch", n=nd, kv=rows)
         self.slot_pos[dec] += 1
         self.slot_budget[dec] -= 1
@@ -1772,7 +1803,13 @@ class ServeEngine:
         budget. Ids for requests that finished while the step was in
         flight (an eos discovered one sync earlier) are discarded — their
         slot was dispatched speculatively."""
-        ids = np.asarray(p.ids)
+        with self.trace.span("serve.decode.sync"):
+            ids = np.asarray(p.ids)
+        with self.trace.span("serve.decode.emit"):
+            self._emit_ids(p, ids)
+
+    def _emit_ids(self, p: _Pending, ids: np.ndarray) -> None:
+        """The bookkeeping of :meth:`_sync` for ids already on the host."""
         n_emitted = 0
         for slot in np.nonzero(p.mask)[0]:
             uid = int(p.uids[slot])
@@ -1803,12 +1840,20 @@ class ServeEngine:
     # ---- engine loop ---------------------------------------------------
     def step(self) -> int:
         """Admit, retry parked handoffs, advance prefill chunks, one decode
-        step. Returns #busy."""
-        self._admit()
-        if self._handoff_ready:
-            self._try_handoffs()
-        self._prefill_chunks()
-        self._decode()
+        step. Returns #busy. Each phase is a profiler span (``STEP_SPANS``
+        in ``repro.obs.trace``) inside ``serve.step``."""
+        with self.trace.span("serve.step"):
+            self._admit()
+            if self._handoff_ready:
+                self._try_handoffs()
+            self._prefill_chunks()
+            self._decode()
+            with self.trace.span("serve.account"):
+                return self._account()
+
+    def _account(self) -> int:
+        """The step's tail: cached and per-device KV gauges, peak
+        concurrency. Returns #busy."""
         # (sched_skips / prefix_evictions need no mirroring: the scheduler
         # and prefix index increment the same registry counters directly)
         if self.prefix_index is not None:

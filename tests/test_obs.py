@@ -1,12 +1,14 @@
 """Observability subsystem unit tests: metrics registry, tracer, reports.
 
-These are pure-Python tests (no model, no jit) — the counters-vs-engine
+Most are pure-Python tests (no model, no jit) — the counters-vs-engine
 ground-truth checks live in tests/test_serve.py and the property oracle in
 tests/test_allocator_props.py; here we pin the *contracts* of the obs
 package itself: instrument semantics, Snapshot algebra and JSON round-trip,
 Prometheus text shape, Chrome-trace structure and its validator's failure
 modes, the StatsView dict compatibility layer, and the zero-division-safe
-paths of the utilization report.
+paths of the utilization report. The last section drives a tiny paged
+engine: its step spans as a ``jax.profiler`` capture records them, and the
+queue-wait and KV-reservation counters against hand-computed values.
 """
 from __future__ import annotations
 
@@ -15,9 +17,9 @@ import json
 import numpy as np
 import pytest
 
-from repro.obs import (NULL_TRACER, MetricsRegistry, NullTracer, Snapshot,
-                       Tracer, decode_utilization, validate_chrome_trace,
-                       windows_from_trace, write_metrics_json)
+from repro.obs import (NULL_TRACER, STEP_SPANS, MetricsRegistry, NullTracer,
+                       Snapshot, Tracer, decode_utilization,
+                       validate_chrome_trace, write_metrics_json)
 
 
 # ---------------------------------------------------------------- metrics --
@@ -279,18 +281,6 @@ def test_decode_utilization_measured_window():
     assert solo["d2d_util"] == 0.0
 
 
-def test_windows_from_trace():
-    t = Tracer(buffer=256, clock=iter(np.arange(0, 10, 0.01)).__next__)
-    for _ in range(8):
-        t.event("dispatch", n=2, kv=24)
-        t.event("sync", n=2, tokens=2)
-    rows = windows_from_trace(t, _cfg(), window_steps=4)
-    assert len(rows) == 2
-    assert rows[0]["steps"] == 4 and rows[0]["tokens"] == 8
-    assert rows[0]["avg_batch"] == pytest.approx(2.0)
-    assert windows_from_trace(NULL_TRACER, _cfg()) == []
-
-
 def test_write_metrics_json_schema(tmp_path):
     reg = MetricsRegistry()
     reg.counter("decode_steps").inc(4)
@@ -304,3 +294,151 @@ def test_write_metrics_json_schema(tmp_path):
     assert on_disk["suite"] == "unit" and on_disk["extra"] == {"note": "x"}
     assert Snapshot.from_json(
         json.dumps(on_disk["snapshot"])) == reg.snapshot()
+
+
+# ------------------------------------------------------------ step spans --
+
+def test_span_is_a_context_manager_without_profiler():
+    """Both tracers hand out a usable span with no profiler running, and a
+    span is no ring event: the ring stays the request-lifecycle record."""
+    for t in (Tracer(buffer=8), NULL_TRACER, NullTracer()):
+        with t.span("serve.step"):
+            with t.span("serve.prefill", uid=3, n=8):
+                pass
+        assert len(t) == 0
+    assert STEP_SPANS[0] == "serve.step" and len(set(STEP_SPANS)) == 8
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    import jax
+
+    from repro.models import init
+    cfg = _cfg()
+    return cfg, init(jax.random.PRNGKey(0), cfg)
+
+
+def _host_spans(root) -> list[tuple[str, int, int, dict]]:
+    """(name, start_ns, end_ns, stats) of every ``serve.*`` host event in
+    the newest capture under ``root``, in start order."""
+    import jax
+
+    [path] = sorted(root.rglob("*.xplane.pb"))
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            out.extend((e.name, int(e.start_ns),
+                        int(e.start_ns + e.duration_ns), dict(e.stats))
+                       for e in line.events if e.name.startswith("serve."))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def test_step_spans_in_profiler_trace(tiny, tmp_path):
+    """A few requests stepped under ``jax.profiler.trace``: every host span
+    is a ``STEP_SPANS`` name, every phase lies inside a ``serve.step``, and
+    there is one sync per decode dispatch and one prefill span per chunk."""
+    import jax
+
+    from repro.serve import Request, ServeEngine
+    cfg, params = tiny
+    rng = np.random.default_rng(5)
+    engine = ServeEngine(cfg, params, max_slots=2, max_len=64, paged=True,
+                         page_size=8, prefill_chunk=8)
+    before = engine.metrics.snapshot()
+    with jax.profiler.trace(str(tmp_path)):
+        for uid, (n, new) in enumerate(((12, 4), (20, 3), (5, 5))):
+            engine.submit(Request(uid=uid, prompt=rng.integers(
+                0, 256, n).astype(np.int32), max_new_tokens=new))
+        steps = 0
+        while engine._busy():
+            engine.step()
+            steps += 1
+    d = engine.metrics.snapshot().delta(before)
+    spans = _host_spans(tmp_path)
+    names = [s[0] for s in spans]
+    assert set(names) == set(STEP_SPANS) - {"serve.spec"}
+    outer = [s for s in spans if s[0] == "serve.step"]
+    assert len(outer) == steps
+    for name, lo, hi, _ in spans:
+        if name != "serve.step":
+            assert any(s[1] <= lo and hi <= s[2] for s in outer), name
+    assert names.count("serve.decode.sync") == d["decode_steps"] > 0
+    assert names.count("serve.decode.dispatch") == d["decode_steps"]
+    assert names.count("serve.prefill") == d["prefill_chunks"] == 2 + 3 + 1
+    assert names.count("serve.admit") == names.count("serve.account") == steps
+    chunks = [s[3] for s in spans if s[0] == "serve.prefill"]
+    assert sorted(c["n"] for c in chunks) == [4, 4, 5, 8, 8, 8]
+    assert {c["uid"] for c in chunks} == {0, 1, 2}
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_queue_wait_and_kv_reserved_counters(tiny, paged):
+    """Two requests through one slot: each admission adds its queue wait,
+    and every decode dispatch reserves the slot's granted pages (a dense
+    slot: ``max_len`` rows), never fewer rows than it attends."""
+    from repro.serve import Request, ServeEngine
+    cfg, params = tiny
+    rng = np.random.default_rng(6)
+    kw = (dict(paged=True, page_size=8, prefill_chunk=8) if paged
+          else dict(paged=False))
+    engine = ServeEngine(cfg, params, max_slots=1, max_len=64, **kw)
+    a = Request(uid=0, prompt=rng.integers(0, 256, 12).astype(np.int32),
+                max_new_tokens=4)            # 16 tokens: 2 pages of 8
+    b = Request(uid=1, prompt=rng.integers(0, 256, 20).astype(np.int32),
+                max_new_tokens=5)            # 25 tokens: 4 pages of 8
+    before = engine.metrics.snapshot()
+    engine.run([a, b])
+    d = engine.metrics.snapshot().delta(before)
+    ra, rb = engine.results[0], engine.results[1]
+    assert d["prefills"] == 2
+    # a waits from its submit to the first step's admission; b until a
+    # has finished, after a's last token and before b's first
+    wait = d["queue_wait_s"]
+    assert ra.token_ts[-1] - rb.submit_s < wait
+    assert wait < (ra.token_ts[0] - ra.submit_s) + (rb.token_ts[0]
+                                                    - rb.submit_s)
+    per_step = (2 * 8, 4 * 8) if paged else (64, 64)
+    assert d["decode_window_kv_reserved_rows"] == (
+        per_step[0] * ra.decode_steps + per_step[1] * rb.decode_steps)
+    assert ra.decode_steps == 3 and rb.decode_steps == 4
+    assert 0 < d["decode_window_kv_rows"] <= d[
+        "decode_window_kv_reserved_rows"]
+
+
+def test_queue_wait_restarts_at_requeue(tiny):
+    """A preempted request's next wait runs from its requeue, not from its
+    submit: the counter stays under the lifecycle ring's admit stamps less
+    each admission's latest submit or requeue, and far under what a wait
+    from the submit would read."""
+    from repro.serve import Request, ServeEngine
+    cfg, params = tiny
+    rng = np.random.default_rng(64)
+    victim = Request(uid=0, prompt=rng.integers(0, 256, 16).astype(np.int32),
+                     max_new_tokens=4)
+    hi = Request(uid=1, prompt=rng.integers(0, 256, 8).astype(np.int32),
+                 max_new_tokens=3, priority=5)
+    ring = Tracer()
+    engine = ServeEngine(cfg, params, max_slots=1, max_len=64, paged=True,
+                         page_size=8, prefill_chunk=4, prefix_cache=True,
+                         preemption=True, tracer=ring)
+    engine.submit(victim)
+    engine.step()                        # admitted, first chunk only
+    engine.submit(hi)
+    while engine._busy():
+        engine.step()
+    res = engine.results[victim.uid]
+    assert res.preempted == 1 and res.queued_s > res.submit_s
+    enqueued = {victim.uid: [res.submit_s, res.queued_s],
+                hi.uid: [engine.results[hi.uid].submit_s]}
+    admits = [e for e in ring.events() if e.name == "admit"]
+    assert [e.uid for e in admits] == [0, 1, 0]
+    assert engine.stats["prefills"] == 3
+    # each admit event is stamped just after its placement: a bound above
+    bound = sum(e.ts - enqueued[e.uid].pop(0) for e in admits)
+    wait = engine.stats["queue_wait_s"]
+    assert 0 < wait <= bound
+    # measured from the submit instead, the victim's second wait would add
+    # everything before its requeue
+    assert bound < wait + (res.queued_s - res.submit_s)
