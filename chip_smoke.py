@@ -107,6 +107,11 @@ def kernel_parity(cfg, seed: int) -> None:
     kq, kscale = quantize_kv(kp, "int8")
     vq, vscale = quantize_kv(vp, "int8")
     kb, vb = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    # pools stacked over two layers, read at layer 1 (the index the layer
+    # scan passes); the other layer holds the opposite pool
+    kb2, vb2 = jnp.stack([vb, kb]), jnp.stack([kb, vb])
+    kq2, vq2 = jnp.stack([vq, kq]), jnp.stack([kq, vq])
+    ksc2, vsc2 = jnp.stack([vscale, kscale]), jnp.stack([kscale, vscale])
 
     d, f = cfg.d_model, cfg.d_ff
     x = jax.random.normal(ks[3], (64, d), jnp.float32).astype(jnp.bfloat16)
@@ -119,10 +124,10 @@ def kernel_parity(cfg, seed: int) -> None:
 
     cases = {
         "paged_attention[bf16 kv]": (ops.paged_attention,
-                                     (q, kb, vb, tables, lengths), {}),
+                                     (q, kb2, vb2, tables, lengths, 1), {}),
         "paged_attention[int8 kv]": (ops.paged_attention,
-                                     (q, kq, vq, tables, lengths, kscale,
-                                      vscale), {}),
+                                     (q, kq2, vq2, tables, lengths, 1, ksc2,
+                                      vsc2), {}),
         "gemm[d_model x d_ff, silu]": (ops.gemm, (x, w_up), {"act": "silu"}),
         "gemm[d_ff x d_model]": (ops.gemm, (h, w_dn.astype(jnp.bfloat16)),
                                  {}),

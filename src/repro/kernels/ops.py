@@ -370,23 +370,33 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 # --------------------------------------------------------------------------
 # paged_attention — block-pool KV decode attention (serving)
 # --------------------------------------------------------------------------
-def _pa_supports(req: OpRequest) -> bool:
-    if len(req.shapes) < 5:
-        return False
-    if len(req.shapes[0]) != 4 or any(len(s) != 4 for s in req.shapes[1:3]):
+def _pa_operands_ok(req: OpRequest) -> bool:
+    """Whether a paged-attention request's operands have the op's layout:
+    q ``(B, K, G, D)``, stacked pools ``(L, N, page, K, D)`` with q's heads
+    and dims, int32 tables, lengths and a scalar layer, and for quantized
+    pools two ``(L, N, page, K)`` scale operands."""
+    if len(req.shapes) < 6 or len(req.shapes[0]) != 4:
         return False
     (B, K, G, D) = req.shapes[0]
-    (N, page, Kp, Dp) = req.shapes[1]
-    # kernel layout: pool heads/dims must match q, and the head dim must
-    # fill at least one sublane — else negotiate down to the gather oracle
-    if not (Kp == K and Dp == D and D >= 8 and _is_float(req.dtypes[0])
-            and all("int" in d for d in req.dtypes[3:5])):
+    if any(len(s) != 5 or s[3:] != (K, D) for s in req.shapes[1:3]):
         return False
-    if len(req.shapes) >= 7:
-        # quantized pools: int8/fp8 storage + (N, page, K) per-row scales
+    if req.shapes[5] != () or not all("int" in d for d in req.dtypes[3:6]):
+        return False
+    return len(req.shapes) < 8 or (req.shapes[6] == req.shapes[1][:4]
+                                   == req.shapes[7])
+
+
+def _pa_supports(req: OpRequest) -> bool:
+    # the head dim must fill at least one sublane, else negotiate down to
+    # the gather oracle
+    if not _pa_operands_ok(req) or req.shapes[0][3] < 8:
+        return False
+    if not _is_float(req.dtypes[0]):
+        return False
+    if len(req.shapes) >= 8:
+        # quantized pools: int8/fp8 storage + per-row float scales
         return (all(d in _QUANT_DTYPES for d in req.dtypes[1:3])
-                and req.shapes[5] == (N, page, K) == req.shapes[6]
-                and all(_is_float(d) for d in req.dtypes[5:7]))
+                and all(_is_float(d) for d in req.dtypes[6:8]))
     return all(_is_float(d) for d in req.dtypes[1:3])
 
 
@@ -394,20 +404,21 @@ def _pa_supports(req: OpRequest) -> bool:
                    backends=("pallas", "interpret"), supports=_pa_supports,
                    priority=10, pass_interpret=True)
 @partial(jax.jit, static_argnames=("scale", "cap", "interpret"))
-def _pa_kernel(q, k_pool, v_pool, block_tables, lengths, k_scale=None,
+def _pa_kernel(q, k_pool, v_pool, block_tables, lengths, layer, k_scale=None,
                v_scale=None, *, scale: float | None = None, cap: float = 0.0,
                interpret: bool = False):
-    return _pa(q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale,
-               scale=scale, cap=cap, interpret=interpret)
+    return _pa(q, k_pool, v_pool, block_tables, lengths, layer, k_scale,
+               v_scale, scale=scale, cap=cap, interpret=interpret)
 
 
 @registry.register("paged_attention", "ref",
                    backends=("ref", "interpret", "pallas"))
 @partial(jax.jit, static_argnames=("scale", "cap"))
-def _pa_ref(q, k_pool, v_pool, block_tables, lengths, k_scale=None,
+def _pa_ref(q, k_pool, v_pool, block_tables, lengths, layer, k_scale=None,
             v_scale=None, *, scale: float | None = None, cap: float = 0.0):
     return _ref.paged_attention_ref(q, k_pool, v_pool, block_tables, lengths,
-                                    k_scale, v_scale, scale=scale, cap=cap)
+                                    layer, k_scale, v_scale, scale=scale,
+                                    cap=cap)
 
 
 def _pa_sharded_supports(req: OpRequest) -> bool:
@@ -416,45 +427,39 @@ def _pa_sharded_supports(req: OpRequest) -> bool:
     head count divides the mesh axis — otherwise the pools were replicated
     by the divisibility-drop rule and the local paths serve unchanged."""
     sm = serve_mesh()
-    if sm is None or len(req.shapes) < 5:
-        return False
-    if len(req.shapes[0]) != 4 or any(len(s) != 4 for s in req.shapes[1:3]):
-        return False
-    (B, K, G, D) = req.shapes[0]
-    (N, page, Kp, Dp) = req.shapes[1]
-    if not (Kp == K and Dp == D
-            and all("int" in d for d in req.dtypes[3:5])):
-        return False
-    if len(req.shapes) >= 7 and not (req.shapes[5] == (N, page, K)
-                                     == req.shapes[6]):
+    if sm is None or not _pa_operands_ok(req):
         return False
     mesh, axis = sm
     n = mesh.shape.get(axis, 1)
-    return n > 1 and K % n == 0
+    return n > 1 and req.shapes[0][1] % n == 0
 
 
 @registry.register("paged_attention", "sharded",
                    backends=("ref", "interpret", "pallas"),
                    supports=_pa_sharded_supports, priority=20)
-def _pa_sharded(q, k_pool, v_pool, block_tables, lengths, k_scale=None,
+def _pa_sharded(q, k_pool, v_pool, block_tables, lengths, layer, k_scale=None,
                 v_scale=None, *, scale: float | None = None,
                 cap: float = 0.0):
     from repro.kernels.paged_attention import paged_attention_sharded
     mesh, axis = serve_mesh()
     return paged_attention_sharded(q, k_pool, v_pool, block_tables, lengths,
-                                   k_scale, v_scale, mesh=mesh, axis=axis,
-                                   scale=scale, cap=cap)
+                                   layer, k_scale, v_scale, mesh=mesh,
+                                   axis=axis, scale=scale, cap=cap)
 
 
-def paged_attention(q, k_pool, v_pool, block_tables, lengths, k_scale=None,
-                    v_scale=None, *, scale: float | None = None,
+def paged_attention(q, k_pool, v_pool, block_tables, lengths, layer,
+                    k_scale=None, v_scale=None, *, scale: float | None = None,
                     cap: float = 0.0, **blocks):
     """Block-pool decode attention. q: (B, K, G, D) one token per slot;
-    k/v pools: (N, page, K, D); block_tables: (B, P) int32; lengths: (B,)
-    int32 valid tokens per slot. ``k_scale``/``v_scale`` ((N, page, K)
-    float) mark quantized (int8/fp8) pools — rows dequantize at read with
-    their per-row absmax scales. Pool layouts the kernel can't express
-    negotiate down to the gather-based oracle."""
+    k/v pools stacked over layers: (L, N, page, K, D), read at ``layer``
+    (int or int32 scalar, may be traced — the layer scan passes its index,
+    so no per-layer slice of the pool is made); block_tables: (B, P) int32;
+    lengths: (B,) int32 valid tokens per slot. A single layer's
+    (N, page, K, D) pool passes as ``pool[None]`` with layer 0.
+    ``k_scale``/``v_scale`` ((L, N, page, K) float) mark quantized
+    (int8/fp8) pools — rows dequantize at read with their per-row absmax
+    scales. Pool layouts the kernel can't express negotiate down to the
+    gather-based oracle."""
     if str(k_pool.dtype) in _QUANT_DTYPES and k_scale is None:
         # negotiation falls back to *correct* paths only: attention over
         # raw int8/fp8 codes would be silent garbage, not a fallback
@@ -462,7 +467,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, k_scale=None,
             f"paged_attention: quantized pools ({k_pool.dtype}) require "
             "k_scale/v_scale per-row dequant scales")
     return registry.dispatch("paged_attention", q, k_pool, v_pool,
-                             block_tables, lengths, k_scale, v_scale,
+                             block_tables, lengths,
+                             jnp.asarray(layer, jnp.int32), k_scale, v_scale,
                              scale=scale, cap=cap, **blocks)
 
 

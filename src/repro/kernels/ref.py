@@ -86,28 +86,30 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, cap=0.0, scale=None):
     return out.reshape(BH, Sq, D)
 
 
-def paged_attention_ref(q, k_pool, v_pool, block_tables, lengths,
+def paged_attention_ref(q, k_pool, v_pool, block_tables, lengths, layer,
                         k_scale=None, v_scale=None, *, scale=None, cap=0.0):
     """Gather-based paged decode attention. q: (B, K, G, D) one token per
-    slot; k/v pools: (N, page, K, D); block_tables: (B, P) int32 pool block
-    ids; lengths: (B,) int32 valid tokens (current included). The slot's
-    sequence is materialized by gathering its pages — row ``p`` of the
-    logical sequence is ``pool[table[b, p // page], p % page]``.
+    slot; k/v pools stacked over layers: (L, N, page, K, D), read at
+    ``layer`` (int32 scalar); block_tables: (B, P) int32 pool block ids;
+    lengths: (B,) int32 valid tokens (current included). The slot's
+    sequence is materialized by gathering its pages straight out of the
+    stacked pool — row ``p`` of the logical sequence is ``pool[layer,
+    table[b, p // page], p % page]``.
 
-    ``k_scale``/``v_scale`` ((N, page, K) float) mark *quantized* pools
+    ``k_scale``/``v_scale`` ((L, N, page, K) float) mark *quantized* pools
     (int8/fp8 storage with per-row absmax scales): gathered rows are
     dequantized before scoring — the read-side half of the quantized paged
     KV cache (docs/quantization.md)."""
     B, K, G, D = q.shape
-    page = k_pool.shape[1]
+    page = k_pool.shape[2]
     P = block_tables.shape[1]
-    k = k_pool[block_tables].reshape(B, P * page, K, D)
-    v = v_pool[block_tables].reshape(B, P * page, K, D)
+    k = k_pool[layer, block_tables].reshape(B, P * page, K, D)
+    v = v_pool[layer, block_tables].reshape(B, P * page, K, D)
     if k_scale is not None:
-        k = k.astype(jnp.float32) * k_scale[block_tables].reshape(
+        k = k.astype(jnp.float32) * k_scale[layer, block_tables].reshape(
             B, P * page, K).astype(jnp.float32)[..., None]
     if v_scale is not None:
-        v = v.astype(jnp.float32) * v_scale[block_tables].reshape(
+        v = v.astype(jnp.float32) * v_scale[layer, block_tables].reshape(
             B, P * page, K).astype(jnp.float32)[..., None]
     scale = (1.0 / jnp.sqrt(D)) if scale is None else scale
     s = jnp.einsum("bkgd,bskd->bkgs", q.astype(jnp.float32),

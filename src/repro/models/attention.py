@@ -233,7 +233,8 @@ def attention_forward(p: Params, cfg: ModelConfig, x, *, is_local: bool,
 
 def attention_decode(p: Params, cfg: ModelConfig, x, cache: dict, *,
                      is_local: bool, pos, compute_dtype, part=None,
-                     cross: bool = False, active=None, block_tables=None):
+                     cross: bool = False, active=None, block_tables=None,
+                     layer=None):
     """Single-token decode against a cache.
 
     cache: {"k": (B, S_buf, K, D), "v": ..., ["slot_pos": (S_buf,) implicit]}
@@ -249,7 +250,10 @@ def attention_decode(p: Params, cfg: ModelConfig, x, cache: dict, *,
     dispatches through the ``paged_attention`` registry op. ``active``
     ((B,) bool) gates cache writes per slot — inactive/prefilling slots
     route their write out of bounds (dropped), so a pooled decode step never
-    scribbles on a slot that is not in the decode phase.
+    scribbles on a slot that is not in the decode phase. ``layer`` (int32
+    scalar) marks paged pools stacked over the scanned layers,
+    ``(n_rep, n_blocks, page, K, D)``: this layer's rows are written and
+    read at that index of the stacked buffer (see ``_paged_decode``).
     Returns (out, new_cache).
     """
     vec_pos = jnp.ndim(pos) > 0  # per-slot positions (continuous batching)
@@ -283,7 +287,7 @@ def attention_decode(p: Params, cfg: ModelConfig, x, cache: dict, *,
             return _paged_decode(p, cfg, q, k, v, cache, pos=posv,
                                  active=active, block_tables=block_tables,
                                  compute_dtype=compute_dtype, x_dtype=x.dtype,
-                                 part=part)
+                                 part=part, layer=layer)
         S_buf = cache["k"].shape[1]
         is_ring = is_local and cfg.window and S_buf == cfg.window
         if is_ring:
@@ -341,15 +345,21 @@ def attention_decode(p: Params, cfg: ModelConfig, x, cache: dict, *,
 
 
 def _paged_decode(p: Params, cfg: ModelConfig, q, k, v, cache, *, pos, active,
-                  block_tables, compute_dtype, x_dtype, part=None):
+                  block_tables, compute_dtype, x_dtype, part=None,
+                  layer=None):
     """Single-token decode against the block-pool (paged) KV layout.
 
     q: (B, 1, K, G, D), k/v: (B, 1, K, D) — already projected, normed, and
     RoPE'd by ``attention_decode``; pos: (B,) int32. cache:
-    {"k"/"v": (N, page, K, D)} global pools. The new token's K/V scatter
-    into the slot's current block (inactive slots dropped via an
-    out-of-bounds block id); the read gathers the slot's pages through
-    ``ops.paged_attention``.
+    {"k"/"v": (N, page, K, D)} global pools of one layer, or with ``layer``
+    (int32 scalar) the pools of every scanned layer stacked,
+    (n_rep, N, page, K, D). The new token's K/V scatter into the slot's
+    current block of that layer (inactive slots dropped via an
+    out-of-bounds block id) and the read gathers the slot's pages through
+    ``ops.paged_attention``, which takes the stacked pool and the layer: a
+    layer scan that carries the stacked pool updates it in place, and no
+    layer's pool is ever sliced out or restacked. A one-layer pool is the
+    leading-1 case at layer 0.
 
     Quantized pools (cache also carries ``k_scale``/``v_scale`` — see
     ``models/cache.py``): the incoming row is quantized per (slot, head)
@@ -358,30 +368,26 @@ def _paged_decode(p: Params, cfg: ModelConfig, q, k, v, cache, *, pos, active,
     """
     B = q.shape[0]
     hd, H = cfg.resolved_head_dim, cfg.n_heads
-    pool_k, pool_v = cache["k"], cache["v"]
-    quantized = "k_scale" in cache
-    n_blocks, page = pool_k.shape[:2]
+    stacked = layer is not None
+    pools = dict(cache) if stacked else {n: a[None] for n, a in cache.items()}
+    layer = jnp.asarray(layer if stacked else 0, jnp.int32)
+    quantized = "k_scale" in pools
+    n_blocks, page = pools["k"].shape[1:3]
     blk = jnp.take_along_axis(block_tables, (pos // page)[:, None],
                               axis=1)[:, 0]
     if active is not None:
         blk = jnp.where(active, blk, n_blocks)  # OOB -> write dropped
     row = pos % page
-    k_sc = v_sc = None
     if quantized:
         from repro.quant import quantize_kv
         kq, ks = quantize_kv(k[:, 0], str(cfg.kv_dtype))      # (B,K,hd),(B,K)
         vq, vs = quantize_kv(v[:, 0], str(cfg.kv_dtype))
-        pool_k = pool_k.at[blk, row].set(kq.astype(pool_k.dtype), mode="drop")
-        pool_v = pool_v.at[blk, row].set(vq.astype(pool_v.dtype), mode="drop")
-        k_sc = cache["k_scale"].at[blk, row].set(
-            ks.astype(cache["k_scale"].dtype), mode="drop")
-        v_sc = cache["v_scale"].at[blk, row].set(
-            vs.astype(cache["v_scale"].dtype), mode="drop")
+        rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
     else:
-        pool_k = pool_k.at[blk, row].set(k[:, 0].astype(pool_k.dtype),
-                                         mode="drop")
-        pool_v = pool_v.at[blk, row].set(v[:, 0].astype(pool_v.dtype),
-                                         mode="drop")
+        rows = {"k": k[:, 0], "v": v[:, 0]}
+    new = {n: pools[n].at[layer, blk, row].set(r.astype(pools[n].dtype),
+                                               mode="drop")
+           for n, r in rows.items()}
     # registry read: an enclosing use_backend scope / cfg.kernel_backend
     # routes through the Pallas kernel; otherwise pin the gather-based ref
     # oracle (the XLA path) — ambient selection (env var / TPU auto) must
@@ -396,15 +402,14 @@ def _paged_decode(p: Params, cfg: ModelConfig, q, k, v, cache, *, pos, active,
                 and getattr(part, "mode", None) == "serve"
                 else contextlib.nullcontext())
     with serve_kv, kdispatch.use_backend(be):
-        out = _reg_pa(q[:, 0], pool_k, pool_v, block_tables, pos + 1,
-                      k_sc, v_sc, scale=_scale(cfg), cap=cfg.attn_softcap)
+        out = _reg_pa(q[:, 0], new["k"], new["v"], block_tables, pos + 1,
+                      layer, new.get("k_scale"), new.get("v_scale"),
+                      scale=_scale(cfg), cap=cfg.attn_softcap)
     out = out.reshape(B, 1, H * hd).astype(compute_dtype)
     out = (out @ p["o_proj"]["kernel"].astype(compute_dtype)).astype(x_dtype)
-    new_cache = {"k": pool_k, "v": pool_v}
-    if quantized:
-        new_cache["k_scale"] = k_sc
-        new_cache["v_scale"] = v_sc
-    return out, new_cache
+    if not stacked:
+        new = {n: a[0] for n, a in new.items()}
+    return out, new
 
 
 def attention_verify(p: Params, cfg: ModelConfig, x, cache: dict, *,

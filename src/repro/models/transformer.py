@@ -141,7 +141,7 @@ def _mask_state(new, old, active):
 def _apply_layer(lp: Params, spec: LayerSpec, cfg: ModelConfig, x, *,
                  positions, enc_out, cache, pos, mode: str, compute_dtype,
                  part=None, active=None, block_tables=None, slot=None,
-                 n_valid=None, first_new_pos=0):
+                 n_valid=None, first_new_pos=0, layer=None):
     """mode: 'full' (train/prefill, builds cache) | 'decode' (single step)
     | 'extend' (chunked prefill: T tokens for ONE slot of the pooled cache)
     | 'verify' (speculative decoding: T tokens for EVERY slot, per-slot
@@ -152,7 +152,9 @@ def _apply_layer(lp: Params, spec: LayerSpec, cfg: ModelConfig, x, *,
     attention layers. Extend extras: ``slot``/``n_valid``/``first_new_pos``
     (traced scalars) — ``first_new_pos`` is where this request's prefill
     started (> 0 when a prefix-cache hit mapped the head of the sequence
-    from shared blocks). Returns (x, new_cache_entry, aux_loss).
+    from shared blocks). ``layer`` (decode, traced scalar): the entry's
+    paged pools are stacked over the scanned layers and this layer lives at
+    that index. Returns (x, new_cache_entry, aux_loss).
     """
     aux = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
@@ -187,7 +189,7 @@ def _apply_layer(lp: Params, spec: LayerSpec, cfg: ModelConfig, x, *,
             out, new_self = attn_mod.attention_decode(
                 lp["attn"], cfg, h, cache["self"], is_local=is_local, pos=pos,
                 compute_dtype=compute_dtype, part=part, active=active,
-                block_tables=bt)
+                block_tables=bt, layer=layer)
             new_cache["self"] = new_self
     elif spec.mixer in ("rglru", "mamba"):
         if mode == "verify":
@@ -296,7 +298,7 @@ def _apply_layers(params: Params, cfg: ModelConfig, x, *, positions, enc_out,
     aux_total = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
 
-    def run(lp, spec, x, centry):
+    def run(lp, spec, x, centry, layer=None):
         if part is not None:
             # ZeRO-3 style: gather this block's FSDP-sharded weights once,
             # in compute dtype, before use (paper C1: stage the tile, then
@@ -307,7 +309,7 @@ def _apply_layers(params: Params, cfg: ModelConfig, x, *, positions, enc_out,
                             compute_dtype=compute_dtype, part=part,
                             active=active, block_tables=block_tables,
                             slot=slot, n_valid=n_valid,
-                            first_new_pos=first_new_pos)
+                            first_new_pos=first_new_pos, layer=layer)
 
     if prefix:
         new_cache["prefix"] = []
@@ -319,25 +321,52 @@ def _apply_layers(params: Params, cfg: ModelConfig, x, *, positions, enc_out,
 
     if n_rep:
         with_cache = cache is not None
+        # paged decode: the full-attention layers' block pools leave the
+        # scanned xs/ys and ride in the carry, stacked (n_rep, N, page, K,
+        # D); each step scatters its rows at [layer, block, row] and the
+        # paged-attention read indexes the layer, so the donated pool is
+        # updated in place instead of sliced out and restacked every step
+        # (ring buffers, recurrent states and cross caches stay scanned)
+        carried = ([j for j, spec in enumerate(pattern)
+                    if spec.mixer == "full"]
+                   if with_cache and mode == "decode"
+                   and block_tables is not None else [])
+        blocks = cache["blocks"] if with_cache else None
+        pools = {j: blocks[j]["self"] for j in carried}
+        if carried:
+            blocks = [{n: e for n, e in c.items()
+                       if not (j in pools and n == "self")}
+                      for j, c in enumerate(blocks)]
 
         def period_body(carry, scanned):
-            x, aux_acc = carry
-            lps, centry = (scanned if with_cache else (scanned, None))
+            x, aux_acc, pools = carry
+            lps, centry, layer = (scanned if with_cache
+                                  else (scanned, None, None))
+            pools = dict(pools)
             ncs = []
             for j, spec in enumerate(pattern):
                 ce = None if centry is None else centry[j]
-                x, nc, aux = run(lps[j], spec, x, ce)
+                if j in pools:
+                    ce = dict(ce, self=pools[j])
+                    x, nc, aux = run(lps[j], spec, x, ce, layer)
+                    pools[j] = nc.pop("self")
+                else:
+                    x, nc, aux = run(lps[j], spec, x, ce)
                 ncs.append(nc)
                 aux_acc = aux_acc + aux
-            return (x, aux_acc), ncs
+            return (x, aux_acc, pools), ncs
 
         body = period_body
         if cfg.remat == "block":
             body = jax.checkpoint(period_body, prevent_cse=False)
-        xs = ((params["blocks"], cache["blocks"]) if with_cache
+        layers = jnp.arange(n_rep, dtype=jnp.int32) if carried else None
+        xs = ((params["blocks"], blocks, layers) if with_cache
               else params["blocks"])
-        (x, aux_total), ncs = jax.lax.scan(body, (x, aux_total), xs,
-                                           unroll=min(cfg.scan_unroll, n_rep))
+        (x, aux_total, pools), ncs = jax.lax.scan(
+            body, (x, aux_total, pools), xs,
+            unroll=min(cfg.scan_unroll, n_rep))
+        for j, pool in pools.items():
+            ncs[j]["self"] = pool
         new_cache["blocks"] = ncs
 
     if rem:

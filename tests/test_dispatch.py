@@ -43,8 +43,8 @@ def _op_calls(dtype):
     table = _rand((64, 32), dtype)
     idx = jax.random.randint(KEY, (37,), 0, 64)
     qd = _rand((3, 2, 4, 16), dtype, seed=4, scale=0.5)   # (B, K, G, D)
-    pool_k = _rand((9, 8, 2, 16), dtype, seed=5, scale=0.5)
-    pool_v = _rand((9, 8, 2, 16), dtype, seed=6)
+    pool_k = _rand((2, 9, 8, 2, 16), dtype, seed=5, scale=0.5)  # 2 layers
+    pool_v = _rand((2, 9, 8, 2, 16), dtype, seed=6)
     tables = jax.random.randint(KEY, (3, 4), 0, 9, jnp.int32)
     lengths = jnp.asarray([5, 17, 30], jnp.int32)
     from repro.kernels.gemm_sparse import block_mask_from_weight
@@ -62,7 +62,7 @@ def _op_calls(dtype):
         "instream_scale_reduce": lambda: ops.instream_scale_reduce(
             x, scale=2.0, shift=-0.5),
         "paged_attention": lambda: ops.paged_attention(
-            qd, pool_k, pool_v, tables, lengths, cap=30.0),
+            qd, pool_k, pool_v, tables, lengths, 1, cap=30.0),
     }
 
 

@@ -208,3 +208,39 @@ def test_instream_property(scale, shift):
     np.testing.assert_allclose(float(got_s),
                                float((np.asarray(x) * scale + shift).sum()),
                                rtol=1e-3, atol=5e-2)
+
+
+# --------------------------------------------------------------------------
+# paged decode attention over layer-stacked pools
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8", "fp8"])
+def test_paged_attention_stacked_layer(kv, layer):
+    """The kernel reads layer ``layer`` of a (L, N, page, K, D) pool through
+    its index maps: it matches the oracle on that layer's own pool, passed
+    as the leading-1 stack at layer 0. The other layers hold different rows,
+    so a wrong layer index cannot pass."""
+    from repro.quant import quantize_kv
+
+    L, B, K, G, D, N, page, P = 3, 3, 2, 4, 16, 9, 8, 4
+    keys = jax.random.split(KEY, 4)
+    q = _rand((B, K, G, D), key=keys[0], scale=0.5)
+    kp = _rand((L, N, page, K, D), key=keys[1], scale=0.5)
+    vp = _rand((L, N, page, K, D), key=keys[2])
+    tables = jax.random.randint(keys[3], (B, P), 0, N, jnp.int32)
+    lengths = jnp.asarray([5, 17, 30], jnp.int32)
+    if kv in ("int8", "fp8"):
+        (kp, ks), (vp, vs) = quantize_kv(kp, kv), quantize_kv(vp, kv)
+        q = q.astype(jnp.float32)
+        scales, one = (ks, vs), (ks[layer][None], vs[layer][None])
+    else:
+        q, kp, vp = (a.astype(kv) for a in (q, kp, vp))
+        scales = one = ()
+    got = ops.paged_attention(q, kp, vp, tables, lengths, layer, *scales,
+                              cap=30.0)
+    want = ref.paged_attention_ref(q, kp[layer][None], vp[layer][None],
+                                   tables, lengths, 0, *one, cap=30.0)
+    tol = 3e-2 if kv == "bfloat16" else 5e-4
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)

@@ -394,20 +394,22 @@ def test_paged_attention_quantized_parity(dtype):
     vp = _rand((N, page, K, D), seed=6)
     tables = jax.random.randint(KEY, (B, P), 0, N, jnp.int32)
     lengths = jnp.asarray([5, 17, 30], jnp.int32)
-    kq, ks = quant.quantize_kv(kp, dtype)
-    vq, vs = quant.quantize_kv(vp, dtype)
+    # one layer's pools: the op's leading-1 stacked layout, layer 0
+    kq, ks = quant.quantize_kv(kp[None], dtype)
+    vq, vs = quant.quantize_kv(vp[None], dtype)
     with use_backend("ref"):
-        want = ops.paged_attention(q, kq, vq, tables, lengths, ks, vs)
+        want = ops.paged_attention(q, kq, vq, tables, lengths, 0, ks, vs)
     with use_backend("interpret"):
-        got = ops.paged_attention(q, kq, vq, tables, lengths, ks, vs)
+        got = ops.paged_attention(q, kq, vq, tables, lengths, 0, ks, vs)
     req = registry.request("paged_attention", q, kq, vq, tables, lengths,
-                           ks, vs)
+                           jnp.int32(0), ks, vs)
     assert registry.select("paged_attention", req,
                            resolve_backend("interpret")).name == "pallas"
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=5e-4, atol=5e-4)
     # and the quantized read stays close to the unquantized pools
-    dense_out = ops.paged_attention(q, kp, vp, tables, lengths)
+    dense_out = ops.paged_attention(q, kp[None], vp[None], tables, lengths,
+                                    0)
     tol = 0.05 if dtype == "int8" else 0.2
     np.testing.assert_allclose(np.asarray(want), np.asarray(dense_out),
                                rtol=tol, atol=tol)
@@ -421,15 +423,15 @@ def test_paged_attention_no_float_page_bounce(dtype):
     materialize a float page-sized (page, D) dequantized copy in-kernel."""
     B, K, G, D, N, page, P = 2, 2, 4, 32, 5, 8, 3
     q = _rand((B, K, G, D), scale=0.5)
-    kq, ks = quant.quantize_kv(_rand((N, page, K, D), seed=1), dtype)
-    vq, vs = quant.quantize_kv(_rand((N, page, K, D), seed=2), dtype)
+    kq, ks = quant.quantize_kv(_rand((1, N, page, K, D), seed=1), dtype)
+    vq, vs = quant.quantize_kv(_rand((1, N, page, K, D), seed=2), dtype)
     tables = jax.random.randint(KEY, (B, P), 0, N, jnp.int32)
     lengths = jnp.asarray([5, 20], jnp.int32)
 
     with use_backend("interpret"):
         jaxpr = jax.make_jaxpr(
             lambda *a: ops.paged_attention(*a))(q, kq, vq, tables, lengths,
-                                                ks, vs)
+                                                0, ks, vs)
 
     bad = []
 
@@ -456,14 +458,15 @@ def test_quantized_pools_without_scales_error_loudly():
     silently run attention over raw codes — the public op refuses."""
     B, K, G, D, N, page, P = 2, 2, 2, 16, 5, 4, 3
     q = _rand((B, K, G, D))
-    kq = jnp.zeros((N, page, K, D), jnp.int8)
+    kq = jnp.zeros((1, N, page, K, D), jnp.int8)
     tables = jnp.zeros((B, P), jnp.int32)
     lengths = jnp.asarray([3, 4], jnp.int32)
-    req = registry.request("paged_attention", q, kq, kq, tables, lengths)
+    req = registry.request("paged_attention", q, kq, kq, tables, lengths,
+                           jnp.int32(0))
     assert registry.select("paged_attention", req,
                            resolve_backend("interpret")).name == "ref"
     with pytest.raises(ValueError, match="k_scale"):
-        ops.paged_attention(q, kq, kq, tables, lengths)
+        ops.paged_attention(q, kq, kq, tables, lengths, 0)
 
 
 # --------------------------------------------------------------------------

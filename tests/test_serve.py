@@ -233,6 +233,90 @@ def test_block_pool_backpressure():
     assert engine.allocator.n_free == engine.allocator.capacity
 
 
+def _gemma2_cfg(**kw):
+    """Gemma2-style [local, global] period: sliding-window ring buffers
+    beside paged global layers in every scanned period."""
+    return reduced(get_arch("gemma2-27b")).replace(
+        n_layers=4, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
+        d_ff=128, vocab_size=256, dtype="float32", window=8, **kw)
+
+
+def _unrolled(cfg, params):
+    """The same model with every layer unrolled out of the layer scan, so
+    each paged layer keeps a pool buffer of its own."""
+    _, pattern, n_rep, rem = cfg.layer_specs()
+    assert not rem
+    layers = [jax.tree.map(lambda a, r=r: a[r], params["blocks"][j])
+              for r in range(n_rep) for j in range(len(pattern))]
+    unrolled = {k: v for k, v in params.items() if k != "blocks"}
+    unrolled["prefix"] = list(params.get("prefix", [])) + layers
+    return cfg.replace(prefix=tuple(cfg.all_layers())), unrolled
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"], ids=["f32-kv", "int8-kv"])
+def test_carried_pools_match_per_layer_pools(kv_dtype):
+    """Decode carries the stacked paged pools through the layer scan and
+    writes and reads them at the layer index, while the ring buffers of the
+    local layers stay scanned. Greedy tokens equal those of the same model
+    with every layer unrolled, where each layer decodes against its own
+    pool (quantized pools carry their scales the same way)."""
+    cfg = _gemma2_cfg(kv_dtype=kv_dtype)
+    params = init(jax.random.PRNGKey(0), cfg)
+    reqs = _mixed_requests(cfg, 6, seed=3, lo=4, hi=20, new_lo=3, new_hi=9)
+    outs = []
+    for c, p in (_unrolled(cfg, params), (cfg, params)):
+        engine = ServeEngine(c, p, max_slots=3, max_len=64, paged=True,
+                             page_size=8, prefill_chunk=6)
+        results = engine.run([Request(uid=r.uid, prompt=r.prompt,
+                                      max_new_tokens=r.max_new_tokens)
+                              for r in reqs])
+        outs.append([r.tokens for r in results])
+        assert engine.allocator.n_free == engine.allocator.capacity
+    local, pool = engine.cache["blocks"]                 # the scanned engine
+    assert local["self"]["k"].shape == (2, 3, 8, 2, 16)   # per-slot rings
+    assert pool["self"]["k"].shape == (2, engine.n_blocks, 8, 2, 16)
+    assert outs[1] == outs[0]
+
+
+def test_decode_pool_updated_in_place_cpu():
+    """The CPU counterpart of the chip's compiled-program check: the decode
+    program (cache donated) holds no temporary that grows with the pool and
+    never copies, restacks or slices the stacked pool. The cache is float32:
+    the CPU backend widens bfloat16 scatters into float32 pool copies."""
+    import re
+
+    cfg = _cfg().replace(n_layers=3)
+    slots, max_len, page = 2, 64, 8
+    eng = ServeEngine(cfg, init(jax.random.PRNGKey(0), cfg),
+                      max_slots=slots, max_len=max_len, paged=True,
+                      page_size=page, max_blocks=8)
+    S, i32 = jax.ShapeDtypeStruct, jnp.int32
+    step = (S((slots, 1), i32), S((slots,), i32), S((slots,), jnp.bool_),
+            S((slots, eng.n_pages), i32), S((slots,), jnp.float32),
+            S((slots,), i32), S((slots,), jnp.float32),
+            S((slots, 2), jnp.uint32), S((slots,), jnp.uint32))
+    temps, pools = [], []
+    for n_blocks in (65, 257):
+        cache = jax.eval_shape(lambda: init_cache(
+            cfg, slots, max_len, n_blocks=n_blocks, page_size=page))
+        compiled = jax.jit(eng._decode_all, donate_argnums=(1,)).lower(
+            eng.params, cache, *step).compile()
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+        pools.append(sum(a.size * a.dtype.itemsize
+                         for a in jax.tree.leaves(cache["blocks"])))
+        stacked = "f32[%d,%d,%d,%d,%d]" % (cfg.n_layers, n_blocks, page,
+                                           cfg.n_kv_heads, cfg.head_dim)
+        layer = "f32[%d,%d,%d,%d]" % (n_blocks, page, cfg.n_kv_heads,
+                                      cfg.head_dim)
+        bad = [line.strip()[:120] for line in compiled.as_text().splitlines()
+               if re.search(r"= (%s|%s)\S* (dynamic-update-slice|"
+                            r"dynamic-slice|copy)" % (re.escape(stacked),
+                                                      re.escape(layer)),
+                            line)]
+        assert not bad, bad
+    assert temps[1] - temps[0] < 0.1 * (pools[1] - pools[0]), (temps, pools)
+
+
 # --------------------------------------------------------------------------
 # prefix caching (refcounted copy-on-write block sharing)
 # --------------------------------------------------------------------------

@@ -28,6 +28,7 @@ D_MODEL, D_FF, VOCAB = 1024, 3072, 151936
 B, K, G, D = 8, 8, 2, 128                # decode slots, KV heads, group, hd
 PAGE, P = 16, 128                        # page rows, pages per slot (2048)
 N = B * P + 1                            # pool blocks incl. the null block
+L = 4                                    # layers of a stacked pool
 
 
 @pytest.fixture(scope="module")
@@ -81,11 +82,13 @@ def test_flash_attention_compiles(one_chip):
 
 @pytest.mark.parametrize("kv", ["bfloat16", "int8", "float8_e4m3fn"])
 def test_paged_attention_compiles(one_chip, kv):
-    shapes = [((B, K, G, D), "bfloat16"), ((N, PAGE, K, D), kv),
-              ((N, PAGE, K, D), kv), ((B, P), "int32"), ((B,), "int32")]
+    # pools stacked over L layers, read at a traced layer index
+    shapes = [((B, K, G, D), "bfloat16"), ((L, N, PAGE, K, D), kv),
+              ((L, N, PAGE, K, D), kv), ((B, P), "int32"), ((B,), "int32"),
+              ((), "int32")]
     if kv != "bfloat16":
         # quantized pools carry float16 per-row scales
-        shapes += [((N, PAGE, K), "float16")] * 2
+        shapes += [((L, N, PAGE, K), "float16")] * 2
     _compile(one_chip, "paged_attention", "pallas", shapes)
 
 
@@ -117,3 +120,63 @@ def test_gather_rows_compiles(one_chip):
     # the embedding lookup's shape: vocab rows of d_model
     _compile(one_chip, "gather_rows", "pallas",
              [((VOCAB, D_MODEL), "bfloat16"), ((64,), "int32")])
+
+
+def test_decode_updates_pool_in_place(one_chip, monkeypatch):
+    """The engine's decode program, compiled for the chip with the cache
+    donated, writes the stacked paged pool in place: its temporaries do not
+    grow with the pool, and no op copies, restacks or allocates a buffer of
+    the stacked pool's shape. A scan that slices each layer's pool out and
+    restacks it holds a pool-sized temporary and fails both checks."""
+    import re
+
+    from repro.configs import get_arch, reduced
+    from repro.models import init
+    from repro.models.cache import init_cache
+    from repro.serve import ServeEngine
+
+    # the registry picks the Pallas kernels by the platform JAX runs on;
+    # the program is built for the described chip, so name its platform
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = reduced(get_arch("qwen3-0.6b")).replace(
+        n_layers=3, d_model=256, n_heads=4, n_kv_heads=2, head_dim=D,
+        d_ff=512, vocab_size=1024, dtype="bfloat16", param_dtype="bfloat16")
+    slots, max_len = 3, 256
+    eng = ServeEngine(cfg, init(jax.random.PRNGKey(0), cfg),
+                      max_slots=slots, max_len=max_len, paged=True,
+                      page_size=PAGE, max_blocks=8, kernel_backend="auto")
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    step = [((slots, 1), i32), ((slots,), i32), ((slots,), jnp.bool_),
+            ((slots, eng.n_pages), i32), ((slots,), jnp.float32),
+            ((slots,), i32), ((slots,), jnp.float32),
+            ((slots, 2), jnp.uint32), ((slots,), jnp.uint32)]
+    params = jax.tree.map(spec, eng.params)
+    temps, pools = [], []
+    # pools well past what the compiler may stage in on-chip memory
+    for n_blocks in (1025, 2049):
+        cache = jax.tree.map(spec, jax.eval_shape(
+            lambda: init_cache(cfg, slots, max_len, n_blocks=n_blocks,
+                               page_size=PAGE)))
+        args = [params, cache] + [jax.ShapeDtypeStruct(
+            s, d, sharding=one_chip) for s, d in step]
+        with eng._kernel_scope():
+            compiled = jax.jit(eng._decode_all, donate_argnums=(1,)).lower(
+                *args).compile()
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+        pools.append(sum(a.size * a.dtype.itemsize
+                         for a in jax.tree.leaves(cache["blocks"])))
+        stacked = "bf16[%d,%d,%d,%d,%d]" % (cfg.n_layers, n_blocks, PAGE,
+                                            cfg.n_kv_heads, D)
+        hlo = compiled.as_text()
+        assert "tpu_custom_call" in hlo          # the Pallas kernels ran
+        bad = [line.strip()[:120] for line in hlo.splitlines()
+               if re.search(r"= " + re.escape(stacked) + r"\S* "
+                            r"(dynamic-update-slice|copy|custom-call\(\))",
+                            line)
+               or (stacked in line and "AllocateBuffer" in line)]
+        assert not bad, bad
+    assert temps[1] - temps[0] < 0.1 * (pools[1] - pools[0]), (temps, pools)
