@@ -61,23 +61,24 @@ def pick_sample(engine, records, seed: int) -> list:
     return sample
 
 
-def served_gaps(w: dict, c: dict, prompt: np.ndarray, served: list[int]
+def served_gaps(a, w: dict, c: dict, prompt: np.ndarray, served: list[int]
                 ) -> np.ndarray:
-    """Reference gap of every served token of one request."""
+    """Reference gap of every served token of one request; ``a`` is the
+    architecture's module (:func:`bench.arch.load`)."""
     from bench import reference
 
     served = np.asarray(served, np.int32)
     seq = np.concatenate([np.asarray(prompt, np.int32), served[:-1]])
     pos = np.arange(len(prompt) - 1, len(seq), dtype=np.int32)
-    gaps, _ = reference.logit_gaps(w, c, seq, pos, served)
+    gaps, _ = reference.logit_gaps(a, w, c, seq, pos, served)
     return gaps
 
 
-def compare(w: dict, c: dict, samples: list[tuple[np.ndarray, list[int]]]
-            ) -> dict:
+def compare(a, w: dict, c: dict,
+            samples: list[tuple[np.ndarray, list[int]]]) -> dict:
     """Readings over the sample: the widest gap, the mean gap over every
     compared token, and how many tokens."""
-    return summarize([served_gaps(w, c, p, s) for p, s in samples])
+    return summarize([served_gaps(a, w, c, p, s) for p, s in samples])
 
 
 def summarize(gaps: list[np.ndarray]) -> dict:

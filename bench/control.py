@@ -60,25 +60,28 @@ def control_choices(params, cfg, prompt, served) -> np.ndarray:
     return out
 
 
-def reference_int8_choices(w, c, prompt, served) -> np.ndarray:
-    """Tokens the reference with int8-rounded weights puts first at every
-    position of ``prompt`` + ``served``: the control where the program's
-    own int8 path cannot hold its weights twice on one chip."""
+def reference_int8_choices(a, w, c, prompt, served) -> np.ndarray:
+    """Tokens the reference with int8-rounded weights (the architecture
+    module ``a``'s ``hidden(..., quant=True)`` and the int8 head) puts
+    first at every position of ``prompt`` + ``served``: the control where
+    the program's own int8 path cannot hold its weights twice on one
+    chip."""
     from bench import reference
 
     seq = np.concatenate([np.asarray(prompt, np.int32),
                           np.asarray(served[:-1], np.int32)])
     pos = np.arange(len(prompt) - 1, len(seq), dtype=np.int32)
-    _, best = reference.logit_gaps(w, c, seq, pos, np.zeros_like(pos),
+    _, best = reference.logit_gaps(a, w, c, seq, pos, np.zeros_like(pos),
                                    quant=True)
     return best
 
 
-def judge(w, c, samples, limits: dict, control: str, *, params=None,
+def judge(a, w, c, samples, limits: dict, control: str, *, params=None,
           cfg=None, failed: int = 0, finished: int = 1) -> dict:
     """The program's readings over ``samples`` (``(prompt, served tokens)``
     pairs) and the control's over the same positions, each with the
-    verdict of :func:`bench.check.verdict` under ``limits``."""
+    verdict of :func:`bench.check.verdict` under ``limits``; ``a`` is the
+    architecture's module (:func:`bench.arch.load`)."""
     from bench import check, reference
 
     gp, gc_ = [], []
@@ -90,8 +93,8 @@ def judge(w, c, samples, limits: dict, control: str, *, params=None,
         if control == "program":
             chosen.append(control_choices(params, cfg, prompt, toks))
         elif control == "reference":
-            chosen.append(reference_int8_choices(w, c, prompt, toks))
-        g, _ = reference.logit_gaps(w, c, seq, pos, np.stack(chosen))
+            chosen.append(reference_int8_choices(a, w, c, prompt, toks))
+        g, _ = reference.logit_gaps(a, w, c, seq, pos, np.stack(chosen))
         g = np.atleast_2d(g)
         gp.append(g[0])
         if control:
@@ -113,7 +116,7 @@ def readings(root: Path, workload: str, seconds: float, seed: int, *,
     """The program's readings on one seed and the control's (``"program"``:
     its int8 weight path; ``"reference"``: the reference at int8; ``""``:
     none), each with its verdict under the cell's limits."""
-    from bench import check, model, run, serve
+    from bench import check, run, serve
 
     cell = run.load_cell(root, workload)
     devices = run._chip_check(cell, require_chip)
@@ -125,9 +128,9 @@ def readings(root: Path, workload: str, seconds: float, seed: int, *,
     su.engine.cache = su.engine.params = None
     su.engine = None
     gc.collect()
-    out = judge(su.w, cell.config, samples, check.load_limits(root, workload),
-                control, params=su.params,
-                cfg=model.program_config(cell.config),
+    out = judge(cell.arch, su.w, cell.config, samples,
+                check.load_limits(root, workload), control, params=su.params,
+                cfg=cell.arch.program_config(cell.config),
                 failed=e2e["failed"], finished=e2e["finished"])
     return {"seed": seed, "clients": su.sizing.clients,
             "max_slots": su.sizing.max_slots, **out}

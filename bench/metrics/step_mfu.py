@@ -13,8 +13,7 @@ def read(ctx):
     s = ctx.shape
     batch = ctx.counters.get("decode_window_batch", 0)
     rows = ctx.counters.get("decode_window_kv_rows", 0)
-    flops = (batch * s.token_flops(0, logits=True)
-             + 4.0 * s.n_layers * s.n_heads * s.head_dim * rows)
+    flops = batch * s.token_flops(0, logits=True) + s.context_flops(rows)
     t0, t1 = ctx.extra["t0"], ctx.extra["t1"]
     sys_len = ctx.extra.get("prefix_tokens", 0)
     for r in ctx.requests:

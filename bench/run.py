@@ -4,12 +4,14 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace 0|1
 
 Everything a cell is made of is found by name from ``BENCHMARK.json``: its
-configuration file (``configs[].file``), its traffic mix
+configuration file (``configs[].file``), the module of that configuration's
+architecture (``bench/arch/<model_type>.py``: weight layout, the program's
+configuration, the float32 reference and the cost counts), its traffic mix
 (``bench/traffic/<traffic>.json``), its correctness limit
 (``bench/limits/<cell>.json``) and, with ``--trace 1``, one reader per
 per-layer metric (``bench/metrics/<metric>.py``, a ``read(ctx)`` that
-returns a number or ``None``). A new cell, mix, configuration or metric is
-new files and entries; this file does not change.
+returns a number or ``None``). A new cell, mix, configuration, architecture
+or metric is new files and entries; this file does not change.
 
 A run makes the weights from the seed on the device, sizes the KV pool as
 the largest whose compiled steps fit the chip (the compiler's
@@ -42,6 +44,8 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
+from bench import arch  # noqa: E402
+
 EXIT_NO_CHIP = 3
 EXIT_BAD_TREE = 2
 
@@ -56,6 +60,7 @@ class Cell:
     name: str
     chips: int
     config: dict            # the configuration file's contents
+    arch: object            # its architecture's module (bench.arch.load)
     traffic: dict           # the traffic mix's parameters
     end_to_end: list        # metric entries this cell reports, trace 0
     per_layer: list         # metric entries this cell reports, trace 1
@@ -77,7 +82,7 @@ def load_cell(root: Path, name: str) -> Cell:
     traffic = json.loads((root / "bench" / "traffic" /
                           f"{w['traffic']}.json").read_text())
     return Cell(name=name, chips=int(w["chips"]), config=config,
-                traffic=traffic,
+                arch=arch.load(config, root), traffic=traffic,
                 end_to_end=[m for m in bench["end_to_end"]
                             if _reports(m, name)],
                 per_layer=[m for m in bench["per_layer"]
@@ -101,7 +106,7 @@ class Context:
     lo: int                         # traced window, trace ns
     hi: int
     counters: dict                  # counter deltas over the window
-    shape: object                   # bench.costs.Shape
+    shape: object                   # the architecture's cost object
     peaks: object                   # bench.costs.Peaks
     engine: dict                    # max_slots, max_len, chunk, page
     requests: list                  # per request: prompt, first token ts...
@@ -181,13 +186,12 @@ def set_up(cell: Cell, seed: int, seconds: float, devices,
 
     from bench import model, serve
     from bench import traffic as traffic_mod
-    from bench.costs import Shape
 
-    c = cell.config
-    shape = Shape.from_config(c)
-    cfg = model.program_config(c)
-    w = model.make_weights(c, seed, device=devices[0])
-    params = model.to_program(w, c)
+    c, a = cell.config, cell.arch
+    shape = a.shape(c)
+    cfg = a.program_config(c)
+    w = model.make_weights(a.weight_shapes(c), seed, device=devices[0])
+    params = a.to_program(w, c)
     model.check_program_layout(params, cfg)
     part = _partitioner(cfg, devices) if cell.chips > 1 else None
     if bytes_limit is None:
@@ -199,7 +203,7 @@ def set_up(cell: Cell, seed: int, seconds: float, devices,
             pool_bytes=0), seed, part=part)
         return serve.step_bytes(probe, params, sz)
 
-    sizing = serve.size_pool(c, cell.traffic, bytes_limit, need,
+    sizing = serve.size_pool(shape, c, cell.traffic, bytes_limit, need,
                              kv_shard=cell.chips)
     engine = serve.make_engine(cfg, c, params, sizing, seed, part=part)
     if fault is not None:
@@ -225,7 +229,7 @@ def run(args, root: Path = ROOT, require_chip: bool = True,
     cell = load_cell(root, args.workload)
     devices = _chip_check(cell, require_chip)
     c = cell.config
-    shape = costs.Shape.from_config(c)
+    shape = cell.arch.shape(c)
     kind = devices[0].device_kind
 
     # ---- set-up: weights, pool, engine, warm-up, clients ----------------
@@ -298,7 +302,7 @@ def run(args, root: Path = ROOT, require_chip: bool = True,
                                   "unit": m["unit"]}
 
     # ---- correctness -----------------------------------------------------
-    readings = check.compare(su.w, c, served)
+    readings = check.compare(cell.arch, su.w, c, served)
     correct, checks = check.verdict(readings, check.load_limits(
         root, cell.name), failed=e2e["failed"], finished=e2e["finished"])
     out = {"correct": bool(correct), "attempted": e2e["attempted"],

@@ -15,7 +15,6 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from bench import traffic as traffic_mod
-from bench.costs import Shape
 
 
 #: share of the chip's memory kept out of the pool beyond what the compiled
@@ -33,12 +32,12 @@ class Sizing:
     need_bytes: int = 0     # the compiled steps' peak at this pool, fitted
 
 
-def at_blocks(c: dict, params: dict, n_blocks: int, kv_shard: int = 1
+def at_blocks(s, c: dict, params: dict, n_blocks: int, kv_shard: int = 1
               ) -> Sizing:
     """The slots a pool of ``n_blocks`` holds at this traffic's mean
     reservation (open loop) or at its largest (closed loop, one slot per
-    client)."""
-    s = Shape.from_config(c)
+    client). ``s`` is the architecture's cost object (its
+    ``kv_token_bytes``)."""
     page = int(c["engine"]["page_size"])
     block_bytes = page * s.kv_token_bytes // kv_shard
     usable = n_blocks - 1                       # block 0 is the null block
@@ -55,8 +54,8 @@ def at_blocks(c: dict, params: dict, n_blocks: int, kv_shard: int = 1
                   clients=int(clients), pool_bytes=int(n_blocks * block_bytes))
 
 
-def size_pool(c: dict, params: dict, bytes_limit: int, need, kv_shard: int = 1
-              ) -> Sizing:
+def size_pool(s, c: dict, params: dict, bytes_limit: int, need,
+              kv_shard: int = 1) -> Sizing:
     """The largest KV pool whose compiled steps fit the chip's memory.
 
     ``need(sizing)`` is the device bytes the step programs take at a pool
@@ -64,14 +63,14 @@ def size_pool(c: dict, params: dict, bytes_limit: int, need, kv_shard: int = 1
     compiler's ``memory_analysis`` reports them). It is read at two small
     pools, where every program compiles, and is affine in the pool; the
     pool is the largest whose fitted need leaves ``HEADROOM`` of
-    ``bytes_limit`` free."""
-    s = Shape.from_config(c)
+    ``bytes_limit`` free. ``s`` is the architecture's cost object (its
+    ``kv_token_bytes`` and ``weight_bytes``)."""
     block_bytes = int(c["engine"]["page_size"]) * s.kv_token_bytes // kv_shard
     budget = int(bytes_limit * (1 - HEADROOM))
     top = (budget - s.weight_bytes) // block_bytes
     probes = []
     for n in (top // 8, top // 4):
-        sz = at_blocks(c, params, n, kv_shard)
+        sz = at_blocks(s, c, params, n, kv_shard)
         if sz.max_slots < 1:
             raise ValueError(f"a pool of {n} blocks of {block_bytes} B holds"
                              f" no request of this traffic")
@@ -79,7 +78,7 @@ def size_pool(c: dict, params: dict, bytes_limit: int, need, kv_shard: int = 1
     (n1, b1), (n2, b2) = probes
     slope = (b2 - b1) / (n2 - n1)
     n = n2 + int((budget - b2) // slope)
-    sz = at_blocks(c, params, n, kv_shard)
+    sz = at_blocks(s, c, params, n, kv_shard)
     if sz.max_slots < 1:
         raise ValueError(f"the KV pool ({n} blocks of {block_bytes} B) "
                          f"holds no request of this traffic")

@@ -4,6 +4,7 @@ it must. Runs use the tiny configuration under ``data/tiny`` on the CPU,
 with the harness's look for a chip skipped."""
 from __future__ import annotations
 
+import hashlib
 import json
 import shutil
 import subprocess
@@ -14,7 +15,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench import costs, model, run, serve, traffic
+from bench import arch, costs, model, run, serve, traffic
 
 HERE = Path(__file__).resolve().parent
 REPO = HERE.parents[1]
@@ -24,9 +25,11 @@ TINY = HERE / "data" / "tiny"
 @pytest.fixture
 def root(tmp_path):
     """A checkout-like root: the tiny BENCHMARK.json and data files, and
-    the real metric readers."""
+    the real metric readers and architecture modules."""
     shutil.copytree(TINY, tmp_path, dirs_exist_ok=True)
-    shutil.copytree(REPO / "bench" / "metrics", tmp_path / "bench" / "metrics")
+    for d in ("metrics", "arch"):
+        shutil.copytree(REPO / "bench" / d, tmp_path / "bench" / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     return tmp_path
 
 
@@ -40,10 +43,40 @@ def _run(root, workload, seed, fault=None, seconds=3.0):
                    bytes_limit=TINY_BYTES)
 
 
+#: an architecture added as a new file: Qwen3 with its embedding scaled by
+#: sqrt(hidden_size), as Gemma's is, which the program, the reference and
+#: the weights have to agree on
+TOY_ARCH = '''"""Qwen3 with its embedding scaled by sqrt(hidden_size)."""
+import dataclasses
+
+import jax.numpy as jnp
+
+from bench.arch import qwen3
+
+weight_shapes = qwen3.weight_shapes
+to_program = qwen3.to_program
+shape = qwen3.shape
+
+
+def program_config(c):
+    return dataclasses.replace(qwen3.program_config(c), embed_scale=True)
+
+
+def hidden(w, c, tokens, quant=False):
+    scale = c["hidden_size"] ** 0.5
+    return qwen3.hidden(dict(w, embed=w["embed"].astype(jnp.float32) * scale),
+                        c, tokens, quant)
+'''
+
+
 # ---- found by name ---------------------------------------------------------
 def test_new_config_traffic_and_metric_are_found_as_new_files(root):
-    """A configuration, a traffic mix and a per-layer metric added as new
-    files plus new entries in BENCHMARK.json; no existing file edited."""
+    """A configuration, a traffic mix, a per-layer metric and an
+    architecture added as new files plus new entries in BENCHMARK.json; no
+    existing file edited. A cell of the new architecture (untied, its
+    embedding scaled) runs to ``correct``; a configuration whose
+    ``model_type`` has no module fails and names the modules there are."""
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
     cfg = json.loads((root / "bench/configs/tiny.json").read_text())
     cfg["name"] = "tiny-wide"
     cfg["intermediate_size"] = 256
@@ -60,6 +93,21 @@ def test_new_config_traffic_and_metric_are_found_as_new_files(root):
     bench["workloads"].append({"name": "tiny-wide.burst",
                                "config": "tiny-wide", "traffic": "tburst",
                                "chips": 1, "why": "test"})
+    toy = dict(json.loads((root / "bench/configs/tiny.json").read_text()),
+               name="toy", model_type="toyscale", tie_word_embeddings=False)
+    (root / "bench/configs/toy.json").write_text(json.dumps(toy))
+    (root / "bench/arch/toyscale.py").write_text(TOY_ARCH)
+    (root / "bench/limits/toy.chat.json").write_text(
+        (root / "bench/limits/tiny.chat.json").read_text())
+    none = dict(toy, name="none", model_type="nonesuch")
+    (root / "bench/configs/none.json").write_text(json.dumps(none))
+    for name in ("toy", "none"):
+        bench["configs"].append({"name": name, "source": "test",
+                                 "file": f"bench/configs/{name}.json",
+                                 "reduced": [], "why": "test"})
+        bench["workloads"].append({"name": f"{name}.chat", "config": name,
+                                   "traffic": "tchat", "chips": 1,
+                                   "why": "test"})
     bench["per_layer"].append({"name": "new_metric", "unit": "steps",
                                "better": "higher",
                                "source": "program_counter", "layer": "x",
@@ -74,7 +122,27 @@ def test_new_config_traffic_and_metric_are_found_as_new_files(root):
                                 run.load_cell(root, "tiny.chat").per_layer]
     read = run.load_reader(root, "new_metric")
     assert read(Namespace(counters={"decode_steps": 3})) == 6.0
-    assert model.program_config(cell.config).d_ff == 256
+    assert cell.arch.program_config(cell.config).d_ff == 256
+    assert all(p.read_bytes() == b for p, b in before.items()
+               if p.name != "BENCHMARK.json")
+
+    toy_cell = run.load_cell(root, "toy.chat")
+    a = toy_cell.arch
+    assert Path(a.__file__) == root / "bench/arch/toyscale.py"
+    assert a.program_config(toy).embed_scale
+    w = model.make_weights(a.weight_shapes(toy), 3)
+    assert "lm_head" in w
+    tokens = np.arange(40) % toy["vocab_size"]
+    qwen3 = arch.load(toy | {"model_type": "qwen3"})
+    assert not np.allclose(a.hidden(w, toy, tokens),
+                           qwen3.hidden(w, toy, tokens), atol=0.1)
+    out = _run(root, "toy.chat", 5)
+    assert out["correct"], out["checks"]
+    assert out["checks"]["tokens_compared"]["value"] >= 192
+
+    with pytest.raises(KeyError, match=r"'nonesuch'.*known: \['llama', "
+                                       r"'qwen3', 'toyscale'\]"):
+        run.load_cell(root, "none.chat")
 
 
 # ---- generators --------------------------------------------------------------
@@ -141,8 +209,8 @@ def test_generator_honours_its_parameters(name):
 
 # ---- arithmetic -----------------------------------------------------------------
 def _shape(name):
-    return costs.Shape.from_config(json.loads(
-        (REPO / "bench/configs" / f"{name}.json").read_text()))
+    c = json.loads((REPO / "bench/configs" / f"{name}.json").read_text())
+    return arch.load(c).shape(c)
 
 
 #: the sizes of Qwen/Qwen3-0.6B's published config.json
@@ -153,7 +221,7 @@ QWEN3_06B = {"hidden_size": 1024, "num_hidden_layers": 28, "head_dim": 128,
 
 
 def test_qwen3_counts_by_hand():
-    s = costs.Shape.from_config(QWEN3_06B)
+    s = arch.load(QWEN3_06B).shape(QWEN3_06B)
     per_layer = 1024 * 128 * (16 + 8 + 8 + 16) + 3 * 1024 * 3072
     assert s.params == 28 * per_layer + 151936 * 1024 + 28 * 2304 + 1024
     assert s.params == 596_049_920
@@ -166,6 +234,7 @@ def test_qwen3_counts_by_hand():
         + 2 * 1024 * 151936)
     assert s.gemm(8, 1024, 3072) == (
         2 * 8 * 1024 * 3072, 2 * (8 * 1024 + 1024 * 3072 + 8 * 3072))
+    assert s.context_flops(100) == 4 * 28 * 16 * 128 * 100
 
 
 def test_deepseek_l15_counts_by_hand():
@@ -178,6 +247,56 @@ def test_deepseek_l15_counts_by_hand():
     assert s.kv_token_bytes == 15 * 2 * 32 * 128 * 2 == 245_760
     assert s.paged_attention(1000) == (4 * 1000 * 32 * 128,
                                        1000 * 2 * 32 * 128 * 2)
+    assert s.context_flops(1000) == 4 * 15 * 32 * 128 * 1000
+
+
+#: what the harness drew and counted before the architectures became
+#: modules of their own (``bench/arch/``): the same names, the same key
+#: split, the same arrays and the same formulas
+TINY_WEIGHTS_SHA256 = ("df810276fc9f7922e0d68cbda68d7a480fada309dc6358f73d"
+                       "92902427cb40b5")
+DEEPSEEK_L15_SHAPES = {
+    "embed": (102400, 4096), "final_norm": (4096,),
+    "attn_norm": (15, 4096), "mlp_norm": (15, 4096),
+    "wq": (15, 4096, 4096), "wk": (15, 4096, 4096), "wv": (15, 4096, 4096),
+    "wo": (15, 4096, 4096), "w_gate": (15, 4096, 11008),
+    "w_up": (15, 4096, 11008), "w_down": (15, 11008, 4096),
+    "lm_head": (4096, 102400)}
+STEP_MFU = 3.3227313588629444
+
+
+def test_weights_of_a_seed_are_pinned():
+    c = json.loads((TINY / "bench/configs/tiny.json").read_text())
+    w = model.make_weights(arch.load(c).weight_shapes(c), 3)
+    h = hashlib.sha256()
+    for k in sorted(w):
+        h.update(k.encode())
+        h.update(np.asarray(w[k]).tobytes())
+    assert h.hexdigest() == TINY_WEIGHTS_SHA256
+
+
+def test_deepseek_weight_shapes_are_pinned():
+    c = json.loads((REPO / "bench/configs/deepseek-7b-l15.json").read_text())
+    assert arch.load(c).weight_shapes(c) == DEEPSEEK_L15_SHAPES
+
+
+def test_step_mfu_is_pinned():
+    """The reader on a fixed context: decode over 24 stepped sequences and
+    60,000 context rows, and one of two prompts whose first token came
+    inside the window."""
+    c = json.loads((REPO / "bench/configs/deepseek-7b-l15.json").read_text())
+    ctx = run.Context(
+        trace=None, lo=0, hi=2_000_000_000,
+        counters={"decode_window_batch": 24, "decode_window_kv_rows": 60_000},
+        shape=arch.load(c).shape(c), peaks=costs.peaks("TPU v5 lite"),
+        engine={"max_slots": 3, "max_len": 4096, "chunk": 256, "page": 16,
+                "n_blocks": 975},
+        requests=[{"prompt": 2042, "max_new": 700, "prefix": -1,
+                   "tokens_ts": [1.0, 1.1]},
+                  {"prompt": 1500, "max_new": 600, "prefix": -1,
+                   "tokens_ts": [0.2, 0.3]}],
+        chips=1, extra={"t0": 0.5, "t1": 2.0, "prefix_tokens": 0})
+    assert run.load_reader(REPO, "step_mfu")(ctx) == STEP_MFU
 
 
 def test_unknown_device_kind_raises():
@@ -194,7 +313,7 @@ def test_configs_match_published_sizes():
     assert d["tie_word_embeddings"] is False
     assert d["num_hidden_layers"] == 15 and d["reduced"] == [
         "num_hidden_layers"]
-    cfg = model.program_config(d)
+    cfg = arch.load(d).program_config(d)
     assert not cfg.tie_embeddings and cfg.n_layers == 15
     assert cfg.param_dtype == "bfloat16"
 
@@ -205,7 +324,7 @@ def test_pool_sizing_fills_memory():
     largest reservation."""
     c = json.loads((REPO / "bench/configs/deepseek-7b-l15.json").read_text())
     mix = json.loads((REPO / "bench/traffic/long-decode.json").read_text())
-    s = costs.Shape.from_config(c)
+    s = arch.load(c).shape(c)
     limit, work = 16_909_336_064, 300_000_000
     probed = []
 
@@ -213,7 +332,7 @@ def test_pool_sizing_fills_memory():
         probed.append(sz.n_blocks)
         return s.weight_bytes + work + 2 * sz.pool_bytes
 
-    sz = serve.size_pool(c, mix, limit, need)
+    sz = serve.size_pool(s, c, mix, limit, need)
     assert len(probed) == 2
     budget = limit * (1 - serve.HEADROOM)
     block = 16 * s.kv_token_bytes
@@ -227,17 +346,18 @@ def test_step_bytes_grow_with_the_pool():
     steps, at the tiny size on the CPU: a larger pool needs more."""
     c = json.loads((TINY / "bench/configs/tiny.json").read_text())
     mix = json.loads((TINY / "bench/traffic/tclosed.json").read_text())
-    cfg = model.program_config(c)
-    params = model.to_program(model.make_weights(c, 1), c)
+    a = arch.load(c)
+    s = a.shape(c)
+    cfg = a.program_config(c)
+    params = a.to_program(model.make_weights(a.weight_shapes(c), 1), c)
     got = []
     for n in (64, 128):
-        sz = serve.at_blocks(c, mix, n)
+        sz = serve.at_blocks(s, c, mix, n)
         probe = serve.make_engine(cfg, c, params, serve.Sizing(
             n_blocks=sz.max_slots + 1, max_slots=sz.max_slots, clients=0,
             pool_bytes=0), 0)
         got.append(serve.step_bytes(probe, params, sz))
-        assert got[-1] > costs.Shape.from_config(c).weight_bytes + (
-            sz.pool_bytes)
+        assert got[-1] > s.weight_bytes + sz.pool_bytes
     assert got[1] > got[0]
 
 
@@ -327,11 +447,12 @@ def test_int8_control_reads_wider_than_the_program(kind):
 
     c = json.loads((TINY / "bench/configs/tinyv.json").read_text())
     limits = check.load_limits(TINY, "tinyv.control")
-    cfg = model.program_config(c)
+    a = arch.load(c)
+    cfg = a.program_config(c)
     for seed in (5, 6, 7, 8):
         rng = np.random.default_rng(seed)
-        w = model.make_weights(c, seed)
-        params = model.to_program(w, c)
+        w = model.make_weights(a.weight_shapes(c), seed)
+        params = a.to_program(w, c)
         engine = serve.make_engine(cfg, c, params, serve.Sizing(
             n_blocks=64, max_slots=4, clients=0, pool_bytes=0), seed)
         prompts = [rng.integers(0, c["vocab_size"], n) for n in (40, 70)]
@@ -340,7 +461,7 @@ def test_int8_control_reads_wider_than_the_program(kind):
         assert serve.drain(engine, [0, 1], 120.0)
         samples = [(p, engine.results[i].tokens)
                    for i, p in enumerate(prompts)]
-        out = control.judge(w, c, samples, limits, kind, params=params,
+        out = control.judge(a, w, c, samples, limits, kind, params=params,
                             cfg=cfg)
         assert out["program"]["correct"], (seed, out)
         assert not out["control"]["correct"], (seed, out)

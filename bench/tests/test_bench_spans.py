@@ -110,7 +110,7 @@ def _ctx(trace, lo, hi, counters=None):
             "decode_steps": 8, "decode_window_batch": 24,
             "decode_window_kv_rows": 60_000, "prefills": 2,
             "queue_wait_s": 0.0007, "decode_window_kv_reserved_rows": 72_000},
-        shape=costs.Shape.from_config(cell.config),
+        shape=cell.arch.shape(cell.config),
         peaks=costs.peaks("TPU v5 lite"),
         engine={"max_slots": 3, "max_len": 4096, "chunk": 256, "page": 16,
                 "n_blocks": 975},
