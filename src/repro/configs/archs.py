@@ -33,6 +33,10 @@ def reduced(cfg: ModelConfig, *, n_layers: int | None = None) -> ModelConfig:
             d_expert=64,
             d_shared=128 if cfg.moe.d_shared else 0,
             n_shared=min(cfg.moe.n_shared, 2))
+    if cfg.mla is not None:
+        kw["mla"] = dataclasses.replace(
+            cfg.mla, kv_lora_rank=64, qk_nope_head_dim=32,
+            qk_rope_head_dim=16, v_head_dim=32)
     if cfg.ssm is not None:
         kw["ssm"] = dataclasses.replace(cfg.ssm, d_state=8, dt_rank=16, chunk=16)
     if cfg.rglru is not None:

@@ -15,8 +15,9 @@ from dataclasses import dataclass, field
 # --------------------------------------------------------------------------
 # Layer specs
 # --------------------------------------------------------------------------
-# mixer: "full" | "local" | "rglru" | "mamba" | "cross" (enc-dec decoder adds
-#        cross attention automatically when cfg.encoder is set)
+# mixer: "full" | "local" | "rglru" | "mamba" | "mla" | "cross" (enc-dec
+#        decoder adds cross attention automatically when cfg.encoder is set;
+#        "mla" is multi-head latent attention, sized by cfg.mla)
 # mlp:   "dense" | "moe" | "none"
 @dataclass(frozen=True)
 class LayerSpec:
@@ -39,6 +40,52 @@ class MoEConfig:
     @property
     def shared_hidden(self) -> int:
         return self.d_shared if self.d_shared else self.n_shared * self.d_expert
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values of every
+    head are up-projected from one ``kv_lora_rank``-wide latent per token,
+    beside a ``qk_rope_head_dim``-wide key that all heads share and that
+    alone carries the rotary positions. The decode cache holds the latent
+    and the shared key only."""
+    kv_lora_rank: int = 512
+    q_lora_rank: int = 0          # query compression: not built, must be 0
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def row_width(self) -> int:
+        """Columns of one cached token per layer: the latent, then the
+        shared RoPE key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (arXiv:2309.00071, as DeepSeek-V2 applies it):
+    inverse frequencies blended between the base ones and those over
+    ``factor`` by a linear ramp between the correction dims of
+    ``beta_fast`` and ``beta_slow`` rotations at
+    ``original_max_position``; cos/sin scaled by ``mscale(mscale) /
+    mscale(mscale_all_dim)``, and the softmax scale of latent attention by
+    ``mscale(mscale_all_dim)**2`` where ``mscale_all_dim`` is set."""
+    kind: str = "yarn"
+    factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def __post_init__(self):
+        if self.kind != "yarn":
+            raise ValueError(f"rope_scaling kind {self.kind!r}; only 'yarn'")
 
 
 @dataclass(frozen=True)
@@ -88,11 +135,13 @@ class ModelConfig:
     sandwich_norms: bool = False  # gemma2: pre+post norms around attn/mlp
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: RopeScaling | None = None
     use_rope: bool = True         # rotary positions
     learned_pos: bool = False     # whisper: learned absolute positions
     max_position: int = 1 << 16   # learned-position table size
     # blocks
     moe: MoEConfig | None = None
+    mla: MLAConfig | None = None
     ssm: SSMConfig | None = None
     rglru: RGLRUConfig | None = None
     encoder: EncoderConfig | None = None
@@ -201,6 +250,12 @@ class ModelConfig:
                            "pallas_interpret": "interpret"}
 
     def __post_init__(self):
+        if self.mla is None and any(sp.mixer == "mla" for sp in
+                                    self.prefix + self.pattern):
+            raise ValueError("an 'mla' mixer needs cfg.mla (MLAConfig)")
+        if self.mla is not None and self.mla.q_lora_rank:
+            raise ValueError("MLAConfig.q_lora_rank: query compression is "
+                             "not built; queries project from x (0)")
         if self.kernel_backend not in ("", "auto", "ref", "interpret",
                                        "pallas"):
             raise ValueError(
@@ -296,6 +351,15 @@ class ModelConfig:
             # mixer
             if spec.mixer in ("full", "local"):
                 p = d * hd * (self.n_heads * 2 + self.n_kv_heads * 2)
+                total += p
+                active += p
+            elif spec.mixer == "mla":
+                a = self.mla
+                H = self.n_heads
+                p = (d * H * a.qk_head_dim + d * a.row_width
+                     + a.kv_lora_rank * H * (a.qk_nope_head_dim
+                                             + a.v_head_dim)
+                     + H * a.v_head_dim * d)
                 total += p
                 active += p
             elif spec.mixer == "rglru":

@@ -6,6 +6,7 @@ import dataclasses
 from repro.configs.base import ModelConfig
 from repro.configs.deepseek_7b import DEEPSEEK_7B
 from repro.configs.deepseek_moe_16b import DEEPSEEK_MOE_16B
+from repro.configs.deepseek_v2_lite import DEEPSEEK_V2_LITE
 from repro.configs.falcon_mamba_7b import FALCON_MAMBA_7B
 from repro.configs.gemma2_27b import GEMMA2_27B
 from repro.configs.llava_next_mistral_7b import LLAVA_NEXT_MISTRAL_7B
@@ -19,7 +20,7 @@ ARCHS: dict[str, ModelConfig] = {
     c.name: c for c in (
         GEMMA2_27B, DEEPSEEK_7B, MINICPM_2B, QWEN3_0_6B, RECURRENTGEMMA_2B,
         WHISPER_TINY, LLAVA_NEXT_MISTRAL_7B, QWEN2_MOE_A2_7B, DEEPSEEK_MOE_16B,
-        FALCON_MAMBA_7B,
+        FALCON_MAMBA_7B, DEEPSEEK_V2_LITE,
     )
 }
 
@@ -33,6 +34,7 @@ SKIPS: dict[tuple[str, str], str] = {
     ("llava-next-mistral-7b", "long_500k"): "pure full attention (quadratic) — per assignment",
     ("qwen2-moe-a2.7b", "long_500k"): "pure full attention (quadratic) — per assignment",
     ("deepseek-moe-16b", "long_500k"): "pure full attention (quadratic) — per assignment",
+    ("deepseek-v2-lite", "long_500k"): "pure full attention (quadratic) — per assignment",
 }
 # gemma2-27b long_500k RUNS: its local layers cap the KV cache at the 4096
 # window and the global layers use a context-parallel (length-sharded) cache.

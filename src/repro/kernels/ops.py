@@ -31,10 +31,12 @@ from repro.kernels.lru_scan import lru_scan as _lru
 from repro.kernels.packed_gather import gather_rows as _gather
 from repro.kernels.packed_gather import packed_gather_rows as _packed_gather
 from repro.kernels.paged_attention import paged_attention as _pa
+from repro.kernels.paged_mla import paged_mla as _pmla
 
 __all__ = ["flash_attention", "gather_rows", "gemm", "gemm_sparse",
            "gemm_sparse_24", "gemm_wq", "instream_scale_reduce", "lru_scan",
-           "packed_gather_rows", "paged_attention", "registry", "use_backend"]
+           "packed_gather_rows", "paged_attention", "paged_mla", "registry",
+           "use_backend"]
 
 #: Storage dtype names of quantized weight/KV operands (str(jnp.dtype)) —
 #: the quant subsystem's canonical list, not a private copy.
@@ -470,6 +472,67 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, layer,
                              block_tables, lengths,
                              jnp.asarray(layer, jnp.int32), k_scale, v_scale,
                              scale=scale, cap=cap, **blocks)
+
+
+# --------------------------------------------------------------------------
+# paged_mla — absorbed latent-attention decode over a paged latent pool
+# --------------------------------------------------------------------------
+def _mla_operands_ok(req: OpRequest) -> bool:
+    """q ``(B, H, D)``, a stacked latent pool ``(L, N, page, D)`` of q's
+    width, int32 tables and lengths, and a scalar layer."""
+    if len(req.shapes) != 5 or len(req.shapes[0]) != 3:
+        return False
+    D = req.shapes[0][2]
+    return (len(req.shapes[1]) == 4 and req.shapes[1][3] == D
+            and req.shapes[4] == ()
+            and all("int" in d for d in req.dtypes[2:5]))
+
+
+def _mla_supports(req: OpRequest) -> bool:
+    # the kernel copies whole rows by DMA: the row must fill whole lane
+    # tiles (the HBM tiling pads a narrower minor dim, and a DMA slice must
+    # be tile-aligned), and so must the value columns
+    if not _mla_operands_ok(req):
+        return False
+    D, dv = req.shapes[0][2], req.param("dv", 0)
+    return (D % 128 == 0 and dv % 128 == 0 and 0 < dv <= D
+            and all(_is_float(d) for d in req.dtypes[:2]))
+
+
+@registry.register("paged_mla", "pallas", backends=("pallas", "interpret"),
+                   supports=_mla_supports, priority=10, pass_interpret=True)
+@partial(jax.jit, static_argnames=("dv", "pages_per_step", "interpret"))
+def _mla_kernel(q, pool, block_tables, lengths, layer, *, dv: int,
+                pages_per_step: int = 32, interpret: bool = False):
+    return _pmla(q, pool, block_tables, lengths, layer, dv=dv,
+                 pages_per_step=pages_per_step, interpret=interpret)
+
+
+@registry.register("paged_mla", "ref", backends=("ref", "interpret", "pallas"))
+@partial(jax.jit, static_argnames=("dv",))
+def _mla_ref(q, pool, block_tables, lengths, layer, *, dv: int):
+    return _ref.paged_mla_ref(q, pool, block_tables, lengths, layer, dv=dv)
+
+
+registry.register_blocks("paged_mla", "*", pages_per_step=32)
+
+
+def paged_mla(q, pool, block_tables, lengths, layer, *, dv: int, **blocks):
+    """Absorbed multi-head latent attention decode (DeepSeek-V2). q:
+    (B, H, D) one pre-scaled absorbed query per slot and head (``[q_nope
+    W_UK, q_pe]``, zero past the row's used columns); pool: latent rows
+    stacked over layers (L, N, page, D), read at ``layer`` (int or int32
+    scalar, may be traced); block_tables: (B, P) int32; lengths: (B,)
+    int32 rows per slot. Returns (B, H, dv): the softmax over the slot's
+    rows of ``q · row``, applied to each row's first ``dv`` columns (the
+    latent). Pool layouts the kernel cannot copy negotiate down to the
+    gather-based oracle."""
+    if str(pool.dtype) in _QUANT_DTYPES:
+        raise ValueError(
+            f"paged_mla: quantized latent pools ({pool.dtype}) are not "
+            "supported")
+    return registry.dispatch("paged_mla", q, pool, block_tables, lengths,
+                             jnp.asarray(layer, jnp.int32), dv=dv, **blocks)
 
 
 # --------------------------------------------------------------------------

@@ -123,6 +123,26 @@ def paged_attention_ref(q, k_pool, v_pool, block_tables, lengths, layer,
     return out.astype(q.dtype)
 
 
+def paged_mla_ref(q, pool, block_tables, lengths, layer, *, dv):
+    """Gather-based absorbed latent-attention decode. q: (B, H, D) one
+    pre-scaled absorbed query per slot and head; pool: latent rows stacked
+    over layers (L, N, page, D), read at ``layer``; block_tables: (B, P)
+    int32; lengths: (B,) int32 rows per slot. Every head scores the slot's
+    rows ``q · row`` and takes the softmax-weighted sum of ``row[:dv]``.
+    Returns (B, H, dv)."""
+    B, H, D = q.shape
+    page = pool.shape[2]
+    P = block_tables.shape[1]
+    rows = pool[layer, block_tables].reshape(B, P * page, D)
+    rows = rows.astype(jnp.float32)
+    s = jnp.einsum("bhd,bsd->bhs", q.astype(jnp.float32), rows)
+    valid = jnp.arange(P * page)[None, :] < lengths[:, None]
+    s = jnp.where(valid[:, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhs,bsd->bhd", p, rows[..., :dv])
+    return out.astype(q.dtype)
+
+
 def lru_scan_ref(a, b, h0=None):
     """Diagonal recurrence h_t = a_t*h_{t-1} + b_t. a, b: (B, L, D)."""
     B, L, D = a.shape

@@ -13,6 +13,17 @@ layout-agnostic up to leaf shapes):
   recurrent (RG-LRU / Mamba) states, and cross-attention caches stay dense
   in both layouts — they are already O(window) / O(1) per slot.
 
+Multi-head latent attention layers (mixer ``"mla"``, DeepSeek-V2) cache
+one *latent row* per token instead of per-head K/V: the RMS-normed
+compressed KV followed by the RoPE'd shared key, ``kv_lora_rank +
+qk_rope_head_dim`` columns, zero-padded to a whole number of 128-lane tiles
+(:func:`latent_width`: 576 -> 640 at DeepSeek-V2-Lite's widths; the TPU's
+HBM tiling pads a 576-wide minor dim to 640 all the same, and the decode
+kernel copies whole rows by DMA). Paged: ``{"lat": (n_blocks, page, W)}``
+under the same block tables, allocator and prefix cache; dense: ``{"lat":
+(batch, max_len, W)}``. Latent pools are bf16/f32 only: a quantized
+``kv_dtype`` is refused.
+
 With ``cfg.kv_dtype`` set to a quantized dtype ("int8" / "fp8"), the paged
 pools store K/V at the narrow width plus per-row float16 absmax scales
 (``k_scale``/``v_scale`` leaves, shape ``(n_blocks, page_size, K)``) —
@@ -37,11 +48,24 @@ from repro.quant import canonical_dtype, is_quant_dtype
 KV_SCALE_DTYPE = jnp.float16
 
 
+def latent_width(cfg: ModelConfig) -> int:
+    """Stored columns of one latent row: the latent and the shared RoPE
+    key, rounded up to whole 128-lane tiles (the pad stays zero)."""
+    return -(-cfg.mla.row_width // 128) * 128
+
+
 def _layer_cache(spec: LayerSpec, cfg: ModelConfig, batch: int, max_len: int,
                  dtype, n_blocks: int = 0, page_size: int = 0) -> dict:
     c: dict = {}
     hd, K = cfg.resolved_head_dim, cfg.n_kv_heads
-    if spec.mixer in ("full", "local"):
+    if spec.mixer == "mla":
+        if is_quant_dtype(cfg.kv_dtype):
+            raise ValueError(
+                f"kv_dtype={cfg.kv_dtype!r}: quantized latent (MLA) pools "
+                "are not supported; latent rows are stored in cfg.dtype")
+        shp = ((n_blocks, page_size) if n_blocks else (batch, max_len))
+        c["self"] = {"lat": jnp.zeros(shp + (latent_width(cfg),), dtype)}
+    elif spec.mixer in ("full", "local"):
         if spec.mixer == "full" and n_blocks:
             # block-pool layout: global pool, no batch dim (slots address it
             # through block tables)
@@ -92,9 +116,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *,
     if prefix:
         cache["prefix"] = [mk(s) for s in prefix]
     if n_rep:
-        per = [mk(s) for s in pattern]
+        # the stacked leaves are made at their full shape: a layer's leaf
+        # broadcast up would hold two more layers' worth while it is copied,
+        # which a pool sized to the chip's memory has no room for
+        per = jax.eval_shape(lambda: [mk(s) for s in pattern])
         cache["blocks"] = jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (n_rep,) + x.shape), per)
+            lambda x: jnp.zeros((n_rep,) + x.shape, x.dtype), per)
     if rem:
         cache["suffix"] = [mk(s) for s in rem]
     return cache
@@ -105,10 +132,10 @@ def cache_bytes(cache) -> int:
 
 
 def kv_bytes(cache, *, pool_n_blocks: int | None = None) -> int:
-    """Bytes of self-attention KV storage (dense buffers + paged pools);
-    recurrent states and cross caches excluded. With ``pool_n_blocks``,
-    count only the paged pool leaves (those sized ``n_blocks`` on their
-    batch-position axis)."""
+    """Bytes of self-attention KV storage (dense buffers + paged pools,
+    latent rows included); recurrent states and cross caches excluded.
+    With ``pool_n_blocks``, count only the paged pool leaves (those sized
+    ``n_blocks`` on their batch-position axis)."""
     total = 0
 
     def f(path, leaf):
@@ -161,8 +188,8 @@ def default_n_blocks(max_slots: int, max_len: int, page_size: int) -> int:
 
 
 def kv_block_bytes(cfg: ModelConfig, page_size: int) -> int:
-    """KV bytes of ONE pool block summed over the paged (global-attention)
-    layers, honoring ``cfg.kv_dtype`` — quantized pools count the storage
+    """KV bytes of ONE pool block summed over the paged (global-attention
+    and latent-attention) layers, honoring ``cfg.kv_dtype`` — quantized pools count the storage
     width plus the per-row scale overhead (``2 × K`` fp16 scalars per row).
     """
     hd, K = cfg.resolved_head_dim, cfg.n_kv_heads
@@ -171,9 +198,13 @@ def kv_block_bytes(cfg: ModelConfig, page_size: int) -> int:
     if is_quant_dtype(cfg.kv_dtype):
         kv_bytes_elem = 2 * jnp.dtype(canonical_dtype(cfg.kv_dtype)).itemsize
         scale_bytes_row = 2 * jnp.dtype(KV_SCALE_DTYPE).itemsize
-    n_paged = sum(1 for sp in cfg.all_layers() if sp.mixer == "full")
+    layers = cfg.all_layers()
+    n_paged = sum(1 for sp in layers if sp.mixer == "full")
     per_row = K * (hd * kv_bytes_elem + scale_bytes_row)
-    return n_paged * page_size * per_row
+    n_latent = sum(1 for sp in layers if sp.mixer == "mla")
+    latent_row = (latent_width(cfg) * jnp.dtype(cfg.dtype).itemsize
+                  if n_latent else 0)
+    return page_size * (n_paged * per_row + n_latent * latent_row)
 
 
 def n_blocks_for_bytes(cfg: ModelConfig, hbm_bytes: int, page_size: int,

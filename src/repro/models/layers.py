@@ -11,6 +11,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import dispatch as kdispatch
 from repro.quant import QuantTensor
@@ -68,14 +69,60 @@ def apply_norm(p: Params, x: jnp.ndarray, kind: str, eps: float) -> jnp.ndarray:
 # --------------------------------------------------------------------------
 # RoPE
 # --------------------------------------------------------------------------
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., seq, n_heads, head_dim); positions: (..., seq) int32."""
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature factor, ``0.1·mscale·ln(factor) + 1``
+    (1 where the positions are not stretched)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(dim: int, theta: float, scaling=None) -> np.ndarray:
+    """Inverse frequencies of a ``dim``-wide rotary embedding, float32.
+    Without ``scaling`` (a :class:`~repro.configs.base.RopeScaling`),
+    ``theta^(-2i/dim)``; with YaRN, those blended with the same over
+    ``factor`` by a linear ramp between the correction dims of
+    ``beta_fast`` and ``beta_slow`` rotations at the original context
+    (low dims keep the base frequencies, high dims are interpolated)."""
+    base = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    if scaling is None:
+        return base.astype(np.float32)
+
+    def corr_dim(rot):
+        return (dim * math.log(scaling.original_max_position
+                               / (rot * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp                  # 1: base frequency, 0: interpolated
+    inv = base / scaling.factor * (1 - keep) + base * keep
+    return inv.astype(np.float32)
+
+
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+         scaling=None) -> jnp.ndarray:
+    """x: (..., seq, n_heads, head_dim); positions: (..., seq) int32.
+    Half-split rotation (``rotate_half``). ``scaling``: YaRN frequencies
+    and cos/sin scale (:func:`rope_inv_freq`)."""
     hd = x.shape[-1]
     half = hd // 2
-    freq = jnp.exp(-math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freq = jnp.exp(-math.log(theta)
+                       * jnp.arange(0, half, dtype=jnp.float32) / half)
+        mscale = 1.0
+    else:
+        freq = jnp.asarray(rope_inv_freq(hd, theta, scaling))
+        mscale = (yarn_mscale(scaling.factor, scaling.mscale)
+                  / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
     ang = positions[..., None].astype(jnp.float32) * freq  # (..., seq, half)
     cos = jnp.cos(ang)[..., None, :]   # (..., seq, 1, half)
     sin = jnp.sin(ang)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1)

@@ -1,4 +1,4 @@
-"""The model spine shared by all 10 assigned architectures.
+"""The model spine shared by all registered architectures.
 
 Layer layout = unrolled ``prefix`` + ``pattern`` × n_repeats (stacked & scanned
 with ``jax.lax.scan``) + unrolled remainder. Scanning the repeated pattern
@@ -41,6 +41,8 @@ def _layer_init(rng, spec: LayerSpec, cfg: ModelConfig, dtype) -> Params:
         if cfg.encoder is not None:  # decoder layer of an enc-dec model
             p["cross_norm"] = norm_init(cfg.d_model, cfg.norm, dtype)
             p["cross"] = attn_mod.attention_init(ks[1], cfg, dtype)
+    elif spec.mixer == "mla":
+        p["attn"] = attn_mod.mla_init(ks[0], cfg, dtype)
     elif spec.mixer == "rglru":
         p["rglru"] = rec_mod.rglru_init(ks[0], cfg, dtype)
     elif spec.mixer == "mamba":
@@ -152,15 +154,42 @@ def _apply_layer(lp: Params, spec: LayerSpec, cfg: ModelConfig, x, *,
     attention layers. Extend extras: ``slot``/``n_valid``/``first_new_pos``
     (traced scalars) — ``first_new_pos`` is where this request's prefill
     started (> 0 when a prefix-cache hit mapped the head of the sequence
-    from shared blocks). ``layer`` (decode, traced scalar): the entry's
-    paged pools are stacked over the scanned layers and this layer lives at
-    that index. Returns (x, new_cache_entry, aux_loss).
+    from shared blocks). ``layer`` (decode, and extend of latent layers;
+    traced scalar): the entry's paged pools are stacked over the scanned
+    layers and this layer lives at that index. Latent-attention (``mla``)
+    layers refuse ``verify``. Expert layers run drop-free in every mode but
+    ``full``. Returns (x, new_cache_entry, aux_loss).
     """
     aux = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
     is_local = spec.mixer == "local"
     h = apply_norm(lp["pre_norm"], x, cfg.norm, cfg.norm_eps)
-    if spec.mixer in ("full", "local"):
+    if spec.mixer == "mla":
+        if mode == "full":
+            width = (cache["self"]["lat"].shape[-1] if cache is not None
+                     else cfg.mla.row_width)
+            out, rows = attn_mod.mla_forward(
+                lp["attn"], cfg, h, positions=positions,
+                compute_dtype=compute_dtype, width=width)
+            if cache is not None:
+                lat = cache["self"]["lat"]
+                new_cache["self"] = {"lat": jnp.zeros_like(lat).at[
+                    :, :rows.shape[1]].set(rows.astype(lat.dtype))}
+        elif mode == "extend":
+            out, new_cache["self"] = attn_mod.mla_extend(
+                lp["attn"], cfg, h, cache["self"], pos=pos, n_valid=n_valid,
+                slot=slot, compute_dtype=compute_dtype,
+                block_tables=block_tables, layer=layer)
+        elif mode == "verify":
+            raise ValueError(
+                "verify_step does not support latent attention (mla): "
+                "speculative decoding is not served with latent pools")
+        else:
+            out, new_cache["self"] = attn_mod.mla_decode(
+                lp["attn"], cfg, h, cache["self"], pos=pos,
+                compute_dtype=compute_dtype, active=active,
+                block_tables=block_tables, layer=layer)
+    elif spec.mixer in ("full", "local"):
         bt = block_tables if spec.mixer == "full" else None
         if mode == "full":
             out, (k, v) = attn_mod.attention_forward(
@@ -254,8 +283,12 @@ def _apply_layer(lp: Params, spec: LayerSpec, cfg: ModelConfig, x, *,
             out = apply_mlp(lp["mlp"], h, cfg.act, cfg.gated_mlp, compute_dtype,
                             part=part)
         else:
+            # serving (decode, chunked prefill, verification) drops no
+            # token: a token's output must not depend on its batch
             out, aux = moe_mod.moe_forward(lp["moe"], cfg, h,
-                                           compute_dtype=compute_dtype, part=part)
+                                           compute_dtype=compute_dtype,
+                                           part=part,
+                                           drop_free=mode != "full")
         if cfg.sandwich_norms:
             out = apply_norm(lp["mlp_post_norm"], out, cfg.norm, cfg.norm_eps)
         x = x + out
@@ -326,11 +359,12 @@ def _apply_layers(params: Params, cfg: ModelConfig, x, *, positions, enc_out,
         # D); each step scatters its rows at [layer, block, row] and the
         # paged-attention read indexes the layer, so the donated pool is
         # updated in place instead of sliced out and restacked every step
-        # (ring buffers, recurrent states and cross caches stay scanned)
+        # (ring buffers, recurrent states and cross caches stay scanned).
+        # Latent (mla) pools ride in the carry in chunked prefill too.
         carried = ([j for j, spec in enumerate(pattern)
-                    if spec.mixer == "full"]
-                   if with_cache and mode == "decode"
-                   and block_tables is not None else [])
+                    if (mode == "decode" and spec.mixer in ("full", "mla"))
+                    or (mode == "extend" and spec.mixer == "mla")]
+                   if with_cache and block_tables is not None else [])
         blocks = cache["blocks"] if with_cache else None
         pools = {j: blocks[j]["self"] for j in carried}
         if carried:

@@ -78,6 +78,7 @@ from repro.models import decode_step, extend_step, forward, logits_fn, \
     verify_step
 from repro.models.cache import copy_block, default_n_blocks, init_cache, \
     kv_bytes, n_blocks_for_bytes, pages_per_slot
+from repro.models.moe import rows_computed as moe_rows_computed
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_TRACER
 from repro.quant import is_quant_dtype, quantize_params
@@ -364,14 +365,31 @@ class ServeEngine:
         # semantics change.
         want_prefix = (cfg.prefix_cache if prefix_cache is None
                        else prefix_cache)
+        # latent attention (mla) keeps all its state in the paged latent
+        # pool, so it counts as full attention here
         self.prefix_capable = (self.paged and cfg.encoder is None
-                               and all(sp.mixer == "full"
+                               and all(sp.mixer in ("full", "mla")
                                        for sp in cfg.all_layers()))
         self.prefix_cache = bool(want_prefix) and self.prefix_capable
         self.prefix_lru = (cfg.prefix_lru if prefix_lru is None
                            else prefix_lru)
         if self.prefix_lru < 0:     # engine kwarg / --prefix-lru bypasses
             raise ValueError("prefix_lru must be >= 0")
+        # latent attention serves from a bf16/f32 latent pool on one
+        # device, without speculation; the rest is not built yet
+        if any(sp.mixer == "mla" for sp in cfg.all_layers()):
+            if is_quant_dtype(cfg.kv_dtype):
+                raise ValueError(
+                    f"kv_dtype={cfg.kv_dtype!r}: quantized latent (mla) "
+                    "pools are not supported")
+            if part is not None:
+                raise ValueError(
+                    "latent attention (mla) does not serve under a "
+                    "Partitioner: sharded latent pools are not supported")
+            if draft_model is not None or cfg.draft_model:
+                raise ValueError(
+                    "speculative decoding does not support latent "
+                    "attention (mla): verify_step has no latent path")
         # SPMD serving: a serve-mode Partitioner shards the paged KV pools
         # (and their per-row quant scales) over the model axis by KV head;
         # everything per-slot — block tables, lengths, sampling state —
@@ -635,6 +653,12 @@ class ServeEngine:
                 ("queue_wait_s",
                  "seconds from each request's latest enqueue (submit or "
                  "requeue) to its placement, summed; one wait per prefills"),
+                ("moe_rows_routed",
+                 "(token, expert) rows routed by decode and prefill "
+                 "steps: tokens x top_k x MoE layers"),
+                ("moe_rows_computed",
+                 "rows the expert matmuls of decode and prefill steps ran, "
+                 "padding included, summed over MoE layers"),
         ):
             self.metrics.counter(name, help_)
         for name, help_ in (
@@ -656,6 +680,9 @@ class ServeEngine:
         self._c_win_kv_res = self.metrics.counter(
             "decode_window_kv_reserved_rows")
         self._c_queue_wait = self.metrics.counter("queue_wait_s")
+        self._c_moe_routed = self.metrics.counter("moe_rows_routed")
+        self._c_moe_computed = self.metrics.counter("moe_rows_computed")
+        self._n_moe = sum(1 for sp in cfg.all_layers() if sp.mlp == "moe")
         self._c_finished = self.metrics.counter(
             "finished", "requests finished, by reason", labels=("reason",))
         self.stats = self.metrics.view()
@@ -1389,6 +1416,7 @@ class ServeEngine:
                             np.int32(self._first_new[slot]))
                 self._slot_ctr[slot] += 1
                 self.stats["prefill_chunks"] += 1
+                self._count_moe_rows(t, self.prefill_chunk)
                 self.trace.event("prefill_chunk", int(self.slot_uid[slot]),
                                  slot=slot, off=off, n=t)
                 off += t
@@ -1792,10 +1820,22 @@ class ServeEngine:
         self._c_win_batch.inc(nd)
         self._c_win_kv.inc(rows)
         self._c_win_kv_res.inc(reserved)
+        self._count_moe_rows(nd, self.max_slots)
         self.trace.event("dispatch", n=nd, kv=rows)
         self.slot_pos[dec] += 1
         self.slot_budget[dec] -= 1
         return _Pending(ids=ids, mask=dec, uids=self.slot_uid.copy())
+
+    def _count_moe_rows(self, tokens: int, rows: int) -> None:
+        """Expert rows of one step that fed ``tokens`` real tokens through
+        a batch of ``rows`` rows (the step's static shape): routed rows
+        count the real tokens, computed rows what the expert matmuls ran
+        (``moe.rows_computed``). From shapes on the host: no transfer."""
+        if not self._n_moe:
+            return
+        m = self.cfg.moe
+        self._c_moe_routed.inc(tokens * m.top_k * self._n_moe)
+        self._c_moe_computed.inc(self._n_moe * moe_rows_computed(m, rows))
 
     def _sync(self, p: _Pending):
         """Bring one dispatched decode step's sampled ids to host and run

@@ -47,6 +47,9 @@ def _op_calls(dtype):
     pool_v = _rand((2, 9, 8, 2, 16), dtype, seed=6)
     tables = jax.random.randint(KEY, (3, 4), 0, 9, jnp.int32)
     lengths = jnp.asarray([5, 17, 30], jnp.int32)
+    # latent attention: 4 heads over 256-column rows, 128 value columns
+    q_lat = _rand((3, 4, 256), dtype, seed=7, scale=0.1)
+    pool_lat = _rand((2, 9, 8, 256), dtype, seed=8)
     from repro.kernels.gemm_sparse import block_mask_from_weight
     mask = block_mask_from_weight(w.astype(jnp.float32), 8, 8, 0.5)
     return {
@@ -63,6 +66,8 @@ def _op_calls(dtype):
             x, scale=2.0, shift=-0.5),
         "paged_attention": lambda: ops.paged_attention(
             qd, pool_k, pool_v, tables, lengths, 1, cap=30.0),
+        "paged_mla": lambda: ops.paged_mla(q_lat, pool_lat, tables, lengths,
+                                           1, dv=128),
     }
 
 

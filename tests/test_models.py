@@ -1,6 +1,6 @@
 """Per-arch smoke tests (reduced same-family configs) + decode equivalence.
 
-Every one of the 10 assigned architectures: instantiate reduced config, run a
+Every one of the 11 registered architectures: instantiate reduced config, run a
 forward/train step on CPU, assert output shapes and no NaNs. Then the serving
 contract: prefill+decode logits == full-forward logits, per family.
 """
@@ -67,7 +67,7 @@ def test_arch_smoke(arch_name):
 
 
 def test_all_archs_registered():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
     fams = {c.family for c in ARCHS.values()}
     assert fams == {"dense", "hybrid", "moe", "ssm", "audio", "vlm"}
 
@@ -77,6 +77,7 @@ def test_all_archs_registered():
     ("qwen3-0.6b", 0.6e9), ("falcon-mamba-7b", 7e9),
     ("deepseek-moe-16b", 16e9), ("qwen2-moe-a2.7b", 14e9),
     ("llava-next-mistral-7b", 7e9), ("recurrentgemma-2b", 2.7e9),
+    ("deepseek-v2-lite", 15.7e9),
 ])
 def test_param_counts_match_billing(name, total_b):
     """Analytic param counts land within 25% of the arch's nameplate size."""

@@ -11,12 +11,14 @@ from repro.configs import (ARCHS, SHAPES, SKIPS, all_cells, get_arch,
 
 
 def test_assignment_coverage():
-    """Exactly the assigned 10 archs x 4 shapes; skips only where the
-    assignment allows (long_500k on full-attention archs)."""
+    """Exactly the 11 registered archs (the assigned 10 and DeepSeek-V2-Lite)
+    x 4 shapes; skips only where the assignment allows (long_500k on
+    full-attention archs)."""
     assert set(ARCHS) == {
         "gemma2-27b", "deepseek-7b", "minicpm-2b", "qwen3-0.6b",
         "recurrentgemma-2b", "whisper-tiny", "llava-next-mistral-7b",
-        "qwen2-moe-a2.7b", "deepseek-moe-16b", "falcon-mamba-7b"}
+        "qwen2-moe-a2.7b", "deepseek-moe-16b", "falcon-mamba-7b",
+        "deepseek-v2-lite"}
     assert set(SHAPES) == {"train_4k", "prefill_32k", "decode_32k",
                            "long_500k"}
     # long_500k runs for sub-quadratic archs only
@@ -24,7 +26,7 @@ def test_assignment_coverage():
     assert set(runs_long) == {"gemma2-27b", "recurrentgemma-2b",
                               "falcon-mamba-7b"}
     assert all(s == "long_500k" for (_, s) in SKIPS)
-    assert len(all_cells(include_skipped=True)) == 40
+    assert len(all_cells(include_skipped=True)) == 44
 
 
 def test_assigned_dims_exact():
@@ -47,6 +49,13 @@ def test_assigned_dims_exact():
     m = get_arch("minicpm-2b")
     assert (m.n_layers, m.d_model, m.n_heads, m.vocab_size) == \
         (40, 2304, 36, 122753)
+    v = get_arch("deepseek-v2-lite")
+    assert (v.n_layers, v.d_model, v.n_heads, v.d_ff, v.vocab_size) == \
+        (27, 2048, 16, 10944, 102400)
+    assert (v.mla.kv_lora_rank, v.mla.qk_nope_head_dim,
+            v.mla.qk_rope_head_dim, v.mla.v_head_dim) == (512, 128, 64, 128)
+    assert (v.moe.n_experts, v.moe.top_k, v.moe.shared_hidden,
+            v.moe.renorm_topk) == (64, 6, 2816, False)
 
 
 def test_shapes_exact():

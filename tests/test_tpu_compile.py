@@ -92,6 +92,19 @@ def test_paged_attention_compiles(one_chip, kv):
     _compile(one_chip, "paged_attention", "pallas", shapes)
 
 
+def test_paged_mla_compiles(one_chip):
+    """Absorbed latent-attention decode at DeepSeek-V2-Lite's widths: 16
+    heads over 640-column latent rows (512 latent + 64 RoPE key, padded to
+    whole lane tiles), 512 value columns, a stacked pool read at a traced
+    layer, 1,024 pages per slot (16k tokens)."""
+    H_MLA, W_MLA, P_MLA = 16, 640, 1024
+    _compile(one_chip, "paged_mla", "pallas",
+             [((B, H_MLA, W_MLA), "bfloat16"),
+              ((L, B * P_MLA + 1, PAGE, W_MLA), "bfloat16"),
+              ((B, P_MLA), "int32"), ((B,), "int32"), ((), "int32")],
+             dv=512)
+
+
 @pytest.mark.parametrize("pack", [1, 2], ids=["int8", "int4"])
 def test_gemm_wq_compiles(one_chip, pack):
     _compile(one_chip, "gemm_wq", "pallas",
