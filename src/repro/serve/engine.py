@@ -298,10 +298,13 @@ class BlockAllocator:
             self.decref(blk, retain=retain)
 
 
+@jax.jit
 def _sample(logits, temps, top_k, top_p, keys):
     """Fused on-device sampler: greedy rows where temp <= 0, top-k/top-p
     filtered temperature sampling otherwise, one PRNG key per row. Runs
-    inside the jitted step: only sampled ids reach the host."""
+    inside the jitted step: only sampled ids reach the host. Jitted itself
+    for the one-shot prefill, which calls it eagerly: an eager
+    ``lax.cond`` would trace and compile its branches on every call."""
     return sample_tokens(logits, temps, top_k, top_p, keys)
 
 
@@ -650,6 +653,10 @@ class ServeEngine:
                  "sum over decode dispatches of context rows attended"),
                 ("decode_window_kv_reserved_rows",
                  "sum over decode dispatches of KV rows reserved"),
+                ("decode_sampled_steps",
+                 "decode dispatches with an active row at temperature > 0 "
+                 "(the sampler's full path; greedy-only steps take the "
+                 "argmax alone)"),
                 ("queue_wait_s",
                  "seconds from each request's latest enqueue (submit or "
                  "requeue) to its placement, summed; one wait per prefills"),
@@ -679,6 +686,7 @@ class ServeEngine:
         self._c_win_kv = self.metrics.counter("decode_window_kv_rows")
         self._c_win_kv_res = self.metrics.counter(
             "decode_window_kv_reserved_rows")
+        self._c_sampled = self.metrics.counter("decode_sampled_steps")
         self._c_queue_wait = self.metrics.counter("queue_wait_s")
         self._c_moe_routed = self.metrics.counter("moe_rows_routed")
         self._c_moe_computed = self.metrics.counter("moe_rows_computed")
@@ -734,6 +742,9 @@ class ServeEngine:
                                     part=self.part, active=active,
                                     block_tables=tables)
         kk = _fold_keys(keys, ctrs, _P_SAMPLE)
+        # an idle slot keeps its last request's temperature: zero it so a
+        # finished sampled request leaves greedy steps on the argmax path
+        temps = jnp.where(active, temps, 0.0)
         return _sample(logits[:, 0], temps, topk, topp, kk), self._pin(cache)
 
     def _chunk_step(self, params, cache, tokens, pos, n_valid, slot, tables,
@@ -1820,6 +1831,8 @@ class ServeEngine:
         self._c_win_batch.inc(nd)
         self._c_win_kv.inc(rows)
         self._c_win_kv_res.inc(reserved)
+        if (self.slot_temp[dec] > 0).any():
+            self._c_sampled.inc()
         self._count_moe_rows(nd, self.max_slots)
         self.trace.event("dispatch", n=nd, kv=rows)
         self.slot_pos[dec] += 1

@@ -10,8 +10,10 @@ static, knobs ride in as traced per-row arrays (``top_k == 0`` and
 ``top_p >= 1`` disable filtering for that row, so one compiled graph serves
 every knob combination).
 
-Greedy rows (temperature <= 0) bypass sampling entirely in
-:func:`sample_tokens`, so filtering can never perturb greedy parity.
+Greedy rows (temperature <= 0) take the argmax in :func:`sample_tokens`;
+a batch with no sampling row skips the filter and the categorical draw
+(decided on the device, inside the one compiled graph), so filtering can
+never perturb greedy parity.
 """
 from __future__ import annotations
 
@@ -88,10 +90,25 @@ def sample_tokens(logits, temps, top_k, top_p, keys):
     otherwise. keys: (B, 2) uint32 — one legacy PRNG key per row, so
     concurrent requests draw from independent, order-independent streams.
     Returns (B,) int32.
+
+    Work follows the rows' knobs, decided on the device: with no row at
+    temp > 0 only the argmax runs; with no sampling row asking for top-k
+    or top-p the tempered logits go straight to the draw
+    (:func:`filter_logits` with both filters off is the identity). Every
+    branch returns what the full formula would, bit for bit.
     """
-    greedy = jnp.argmax(logits, axis=-1)
-    t = jnp.where(temps <= 0, 1.0, temps)[:, None]
-    f = filter_logits(logits.astype(jnp.float32) / t, top_k, top_p)
-    sampled = jax.vmap(
-        lambda k, row: jax.random.categorical(k, row))(keys, f)
-    return jnp.where(temps <= 0, greedy, sampled).astype(jnp.int32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampling = temps > 0
+
+    def draw():
+        t = jnp.where(temps <= 0, 1.0, temps)[:, None]
+        f = logits.astype(jnp.float32) / t
+        filtered = sampling & ((top_k > 0) | (top_p < 1.0))
+        f = jax.lax.cond(jnp.any(filtered),
+                         lambda x: filter_logits(x, top_k, top_p),
+                         lambda x: x, f)
+        sampled = jax.vmap(
+            lambda k, row: jax.random.categorical(k, row))(keys, f)
+        return jnp.where(temps <= 0, greedy, sampled).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(sampling), draw, lambda: greedy)

@@ -865,3 +865,51 @@ def test_on_device_sampling_temperature(setup):
     assert all(len(r.tokens) == 5 for r in res)
     assert all(0 <= t < cfg.vocab_size for r in res for t in r.tokens)
     assert res[1].tokens == _ref_greedy(cfg, params, prompt, 5)
+
+
+def test_decode_sampled_steps_counts_sampling_dispatches(setup, monkeypatch):
+    """``decode_sampled_steps`` counts the decode dispatches with an active
+    row at temperature > 0; idle slots reach the sampler at temperature 0,
+    so a greedy request admitted after a sampled one has finished keeps
+    every step on the argmax path (the device sees only zeros)."""
+    import repro.serve.engine as engine_mod
+    cfg, params = setup
+    seen = []
+    sample = engine_mod._sample
+
+    def spy(logits, temps, top_k, top_p, keys):
+        jax.debug.callback(lambda t: seen.append(np.asarray(t)), temps)
+        return sample(logits, temps, top_k, top_p, keys)
+
+    monkeypatch.setattr(engine_mod, "_sample", spy)
+    rng = np.random.default_rng(17)
+    prompt = rng.integers(0, 256, 6).astype(np.int32)
+    engine = ServeEngine(cfg, params, max_slots=2, max_len=96, seed=3)
+
+    def run(reqs):
+        before = (engine.stats["decode_sampled_steps"],
+                  engine.stats["decode_steps"])
+        seen.clear()
+        res = engine.run(reqs)
+        jax.effects_barrier()
+        return (res, engine.stats["decode_sampled_steps"] - before[0],
+                engine.stats["decode_steps"] - before[1])
+
+    res, sampled, steps = run([
+        Request(uid=0, prompt=prompt, max_new_tokens=5),
+        Request(uid=1, prompt=prompt, max_new_tokens=4)])
+    assert steps > 0 and sampled == 0
+    assert res[0].tokens == _ref_greedy(cfg, params, prompt, 5)
+    # both slots now hold sampled requests: every decode step samples
+    res, sampled, steps = run([
+        Request(uid=2, prompt=prompt, max_new_tokens=5, temperature=1.0),
+        Request(uid=3, prompt=prompt, max_new_tokens=5, temperature=1.0)])
+    assert all(len(r.tokens) == 5 for r in res)
+    assert steps == 4 and sampled == steps
+    # one greedy request beside the other slot's stale temperature
+    res, sampled, steps = run([
+        Request(uid=4, prompt=prompt, max_new_tokens=5)])
+    assert steps == 4 and sampled == 0
+    assert res[0].tokens == _ref_greedy(cfg, params, prompt, 5)
+    assert engine.slot_temp.max() == 1.0, "a slot keeps its stale temp"
+    assert seen and all((t == 0).all() for t in seen)

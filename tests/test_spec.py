@@ -24,7 +24,8 @@ import pytest
 from repro.configs import get_arch, reduced
 from repro.models import init as model_init
 from repro.serve.engine import Request, ServeEngine
-from repro.spec import filter_logits, filtered_probs, speculative_accept
+from repro.spec import (filter_logits, filtered_probs, sample_tokens,
+                        speculative_accept)
 
 # chi-squared critical values at alpha = 0.001 (no scipy on the container)
 CHI2_999 = {7: 24.322, 15: 37.697, 31: 61.098}
@@ -77,6 +78,45 @@ def test_filters_disabled_are_identity():
     logits = jnp.asarray(rng.normal(size=(3, 16)), jnp.float32)
     out = filter_logits(logits, jnp.zeros(3, jnp.int32), jnp.ones(3))
     np.testing.assert_allclose(np.asarray(out), np.asarray(logits))
+
+
+def _sample_tokens_full(logits, temps, top_k, top_p, keys):
+    """The fused sampler's formula before it branched on the knobs: every
+    row filtered and drawn, greedy rows selected out afterwards."""
+    greedy = jnp.argmax(logits, axis=-1)
+    t = jnp.where(temps <= 0, 1.0, temps)[:, None]
+    f = filter_logits(logits.astype(jnp.float32) / t, top_k, top_p)
+    sampled = jax.vmap(
+        lambda k, row: jax.random.categorical(k, row))(keys, f)
+    return jnp.where(temps <= 0, greedy, sampled).astype(jnp.int32)
+
+
+@pytest.mark.parametrize("temps,top_k,top_p", [
+    # every row greedy, filters set on some of them
+    ([0.0, 0.0, 0.0, 0.0, 0.0, 0.0], [0, 5, 0, 1, 0, 3],
+     [1.0, 1.0, 0.5, 1.0, 0.9, 0.2]),
+    # one sampled row with top-k among greedy rows
+    ([0.0, 0.8, 0.0, 0.0, 0.0, 0.0], [0, 7, 0, 0, 2, 0],
+     [1.0, 1.0, 1.0, 0.3, 1.0, 1.0]),
+    # sampled rows with no filter (greedy rows may still carry one)
+    ([1.0, 0.0, 0.7, 1.3, 0.0, 2.0], [0, 4, 0, 0, 0, 0],
+     [1.0, 1.0, 1.0, 1.0, 0.5, 1.0]),
+    # every row sampled with top-p
+    ([1.0, 0.5, 0.9, 1.2, 2.0, 0.3], [0, 0, 0, 0, 0, 0],
+     [0.9, 0.5, 0.95, 0.8, 0.99, 0.7]),
+], ids=["all-greedy", "one-top-k", "unfiltered", "all-top-p"])
+def test_sample_tokens_matches_full_formula(temps, top_k, top_p):
+    rng = np.random.default_rng(5)
+    B, V = len(temps), 64
+    logits = jnp.asarray(rng.normal(scale=2.0, size=(B, V)), jnp.float32)
+    args = (logits, jnp.asarray(temps, jnp.float32),
+            jnp.asarray(top_k, jnp.int32), jnp.asarray(top_p, jnp.float32))
+    for seed in range(8):
+        keys = jax.random.split(jax.random.PRNGKey(seed), B)
+        got = jax.jit(sample_tokens)(*args, keys)
+        want = jax.jit(_sample_tokens_full)(*args, keys)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
