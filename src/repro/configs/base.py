@@ -239,15 +239,6 @@ class ModelConfig:
     # graph. A use_backend(...) scope around the model call overrides this
     # field. Read through ``resolved_kernel_backend``.
     kernel_backend: str = ""      # "" | auto | ref | interpret | pallas
-    # DEPRECATED: pre-registry attention switch. Non-default values emit a
-    # DeprecationWarning and map onto the kernel backend ("pallas" ->
-    # "pallas", "pallas_interpret" -> "interpret") unless kernel_backend is
-    # set explicitly; resolution happens in ``resolved_kernel_backend`` so
-    # replace(attention_impl="xla") round-trips back to the XLA paths.
-    attention_impl: str = "xla"   # deprecated: xla | pallas | pallas_interpret
-
-    _ATTENTION_IMPL_MAP = {"xla": "", "pallas": "pallas",
-                           "pallas_interpret": "interpret"}
 
     def __post_init__(self):
         if self.mla is None and any(sp.mixer == "mla" for sp in
@@ -299,24 +290,11 @@ class ModelConfig:
             raise ValueError("quant_block must be >= 0")
         if not 0.0 < self.weight_density <= 1.0:
             raise ValueError("weight_density must be in (0, 1]")
-        if self.attention_impl not in self._ATTENTION_IMPL_MAP:
-            raise ValueError(
-                f"attention_impl={self.attention_impl!r}; expected 'xla', "
-                "'pallas', or 'pallas_interpret'")
-        if self.attention_impl != "xla":
-            import warnings
-            warnings.warn(
-                "ModelConfig.attention_impl is deprecated; use "
-                "kernel_backend='pallas' / 'interpret' (kernel selection now "
-                "flows through repro.kernels.dispatch)",
-                DeprecationWarning, stacklevel=3)
 
     @property
     def resolved_kernel_backend(self) -> str:
-        """Backend for model layers: explicit kernel_backend, else the
-        deprecated attention_impl mapping, else "" (pure-XLA paths)."""
-        return (self.kernel_backend
-                or self._ATTENTION_IMPL_MAP[self.attention_impl])
+        """Backend for model layers: "" keeps the pure-XLA paths."""
+        return self.kernel_backend
 
     # ---- derived ----
     @property

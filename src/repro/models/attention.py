@@ -34,8 +34,6 @@ from __future__ import annotations
 
 import contextlib
 import math
-from functools import partial
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -284,10 +282,10 @@ def attention_decode(p: Params, cfg: ModelConfig, x, cache: dict, *,
     vec_pos = jnp.ndim(pos) > 0  # per-slot positions (continuous batching)
     B = x.shape[0]
     hd, H, K = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-    G = H // K
-    xc = x.astype(compute_dtype)  # (B, 1, d)
-    q = (xc @ p["q_proj"]["kernel"].astype(compute_dtype)).reshape(B, 1, K, G, hd)
     if cross:
+        q = (x.astype(compute_dtype)
+             @ p["q_proj"]["kernel"].astype(compute_dtype)).reshape(
+                 B, 1, K, H // K, hd)
         k_all, v_all = cache["k"], cache["v"]
         new_cache = cache
         if cfg.qk_norm:
@@ -296,18 +294,9 @@ def attention_decode(p: Params, cfg: ModelConfig, x, cache: dict, *,
         slot_pos = jnp.arange(S_buf)
         valid = slot_pos[None, :] < cache.get("len", S_buf)
     else:
-        k = (xc @ p["k_proj"]["kernel"].astype(compute_dtype)).reshape(B, 1, K, hd)
-        v = (xc @ p["v_proj"]["kernel"].astype(compute_dtype)).reshape(B, 1, K, hd)
-        if cfg.qk_norm:
-            q = apply_norm(p["q_norm"], q, "rmsnorm", cfg.norm_eps)
-            k = apply_norm(p["k_norm"], k, "rmsnorm", cfg.norm_eps)
-        if cfg.use_rope:
-            posb = (pos[:, None].astype(jnp.int32) if vec_pos
-                    else jnp.full((B, 1), pos, jnp.int32))
-            qf = rope(q.reshape(B, 1, H, hd), posb, cfg.rope_theta,
-                      cfg.rope_scaling)
-            q = qf.reshape(B, 1, K, G, hd)
-            k = rope(k, posb, cfg.rope_theta, cfg.rope_scaling)
+        posb = (pos[:, None].astype(jnp.int32) if vec_pos
+                else jnp.full((B, 1), pos, jnp.int32))
+        q, k, v = _project_qkv(p, cfg, x, x, posb, posb, compute_dtype)
         if block_tables is not None:
             posv = pos if vec_pos else jnp.full((B,), pos, jnp.int32)
             return _paged_decode(p, cfg, q, k, v, cache, pos=posv,
@@ -370,6 +359,54 @@ def attention_decode(p: Params, cfg: ModelConfig, x, cache: dict, *,
     return out, new_cache
 
 
+def _write_kv_rows(cfg: ModelConfig, pool: dict, idx, k, v):
+    """Scatter new K/V rows into the paged ``pool`` at ``idx`` (a tuple of
+    index arrays; out-of-bounds rows drop). A quantized pool (it carries
+    ``k_scale``/``v_scale``, see ``models/cache.py``) takes each (row,
+    head) quantized with its own absmax scale. Returns (the new pool, the
+    rows as stored)."""
+    rows = {"k": k, "v": v}
+    if "k_scale" in pool:
+        from repro.quant import quantize_kv
+        for n in ("k", "v"):
+            rows[n], rows[n + "_scale"] = quantize_kv(rows[n],
+                                                      str(cfg.kv_dtype))
+    rows = {n: r.astype(pool[n].dtype) for n, r in rows.items()}
+    return ({n: pool[n].at[idx].set(r, mode="drop") for n, r in rows.items()},
+            rows)
+
+
+def _read_kv(pool: dict, compute_dtype):
+    """K and V of stored pool rows (``{"k", "v"[, "k_scale", "v_scale"]}``
+    indexed alike) in ``compute_dtype``, dequantized when scales ride
+    along."""
+    if "k_scale" not in pool:
+        return pool["k"].astype(compute_dtype), pool["v"].astype(compute_dtype)
+    from repro.quant import dequantize_kv
+    return tuple(dequantize_kv(pool[n], pool[n + "_scale"], compute_dtype)
+                 for n in ("k", "v"))
+
+
+def _attend_chunk(p: Params, cfg: ModelConfig, q, k_old, v_old, k, v, mask,
+                  compute_dtype, x_dtype):
+    """Softmax of T queries over ``[snapshot | chunk]`` keys, then W_o.
+    q: (B, T, K, G, D); k_old/v_old: (B, S, K, D); k/v: (B, T, K, D);
+    mask: (B or 1, T, S + T). Returns (B, T, d)."""
+    B, T = q.shape[:2]
+    s_old = jnp.einsum("btkgd,bskd->bkgts", q, k_old.astype(compute_dtype),
+                       preferred_element_type=jnp.float32) * _scale(cfg)
+    s_new = jnp.einsum("btkgd,bskd->bkgts", q, k.astype(compute_dtype),
+                       preferred_element_type=jnp.float32) * _scale(cfg)
+    s = softcap(jnp.concatenate([s_old, s_new], axis=-1), cfg.attn_softcap)
+    s = jnp.where(mask[:, None, None], s, NEG_INF)
+    w = jax.nn.softmax(s, axis=-1)
+    vv = jnp.concatenate([v_old, v], axis=1).astype(compute_dtype)
+    out = jnp.einsum("bkgts,bskd->btkgd", w.astype(compute_dtype), vv,
+                     preferred_element_type=jnp.float32)
+    out = out.reshape(B, T, -1).astype(compute_dtype)
+    return (out @ p["o_proj"]["kernel"].astype(compute_dtype)).astype(x_dtype)
+
+
 def _paged_decode(p: Params, cfg: ModelConfig, q, k, v, cache, *, pos, active,
                   block_tables, compute_dtype, x_dtype, part=None,
                   layer=None):
@@ -387,33 +424,23 @@ def _paged_decode(p: Params, cfg: ModelConfig, q, k, v, cache, *, pos, active,
     layer's pool is ever sliced out or restacked. A one-layer pool is the
     leading-1 case at layer 0.
 
-    Quantized pools (cache also carries ``k_scale``/``v_scale`` — see
-    ``models/cache.py``): the incoming row is quantized per (slot, head)
-    with its own absmax scale before the scatter, and the registry read
-    dequantizes — KV crosses HBM at storage width both ways.
+    Quantized pools (cache also carries ``k_scale``/``v_scale``): the
+    incoming row is quantized before the scatter (``_write_kv_rows``) and
+    the registry read dequantizes — KV crosses HBM at storage width both
+    ways.
     """
     B = q.shape[0]
     hd, H = cfg.resolved_head_dim, cfg.n_heads
     stacked = layer is not None
     pools = dict(cache) if stacked else {n: a[None] for n, a in cache.items()}
     layer = jnp.asarray(layer if stacked else 0, jnp.int32)
-    quantized = "k_scale" in pools
     n_blocks, page = pools["k"].shape[1:3]
     blk = jnp.take_along_axis(block_tables, (pos // page)[:, None],
                               axis=1)[:, 0]
     if active is not None:
         blk = jnp.where(active, blk, n_blocks)  # OOB -> write dropped
-    row = pos % page
-    if quantized:
-        from repro.quant import quantize_kv
-        kq, ks = quantize_kv(k[:, 0], str(cfg.kv_dtype))      # (B,K,hd),(B,K)
-        vq, vs = quantize_kv(v[:, 0], str(cfg.kv_dtype))
-        rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    else:
-        rows = {"k": k[:, 0], "v": v[:, 0]}
-    new = {n: pools[n].at[layer, blk, row].set(r.astype(pools[n].dtype),
-                                               mode="drop")
-           for n, r in rows.items()}
+    new, _ = _write_kv_rows(cfg, pools, (layer, blk, pos % page), k[:, 0],
+                            v[:, 0])
     # registry read: an enclosing use_backend scope / cfg.kernel_backend
     # routes through the Pallas kernel; otherwise pin the gather-based ref
     # oracle (the XLA path) — ambient selection (env var / TPU auto) must
@@ -438,122 +465,72 @@ def _paged_decode(p: Params, cfg: ModelConfig, q, k, v, cache, *, pos, active,
     return out, new
 
 
-def attention_verify(p: Params, cfg: ModelConfig, x, cache: dict, *,
-                     pos, n_valid, active, block_tables, compute_dtype):
-    """Score T tokens per slot in ONE pass against the paged pools — the
-    verifier side of speculative decoding, and the fork re-decode.
+def attention_paged_extend(p: Params, cfg: ModelConfig, x, cache: dict, *,
+                           pos, n_valid, active, block_tables,
+                           compute_dtype):
+    """Score T new tokens per slot in ONE pass against the paged pools:
+    chunked prefill (one slot, through its table row, ``active`` None),
+    the verifier side of speculative decoding and the fork re-decode (every
+    slot).
 
     x: (B, T, d) — slot b's tokens sit at absolute positions
     ``pos[b] .. pos[b]+T-1``; only the first ``n_valid[b]`` are real (the
     rest are padding whose writes drop and whose outputs are junk).
-    ``active`` ((B,) bool) gates whole slots exactly like decode. Paged
-    pools only: this is ``attention_extend``'s scatter/snapshot scheme
-    batched over slots, with per-slot masks replacing the traced scalars.
-    Rows past a slot's table (positions beyond ``P * page``) also drop, so
-    a speculative chunk near ``max_len`` cannot scribble out of range.
+    ``pos``/``n_valid`` are (B,) int32; ``active`` ((B,) bool or None)
+    gates whole slots exactly like decode. ``cache`` is one layer's pools
+    ``{"k"/"v": (N, page, K, D)[, "k_scale"/"v_scale"]}`` and
+    ``block_tables`` (B, P) the slots' tables. Rows past a slot's table
+    (positions beyond ``P * page``) also drop, so a speculative chunk near
+    ``max_len`` cannot scribble out of range.
+
+    The chunk's rows are written (quantized for quantized pools); the
+    queries read a pre-write snapshot of each slot's pages (positions below
+    ``pos[b]``, prefix-shared pages included) and the chunk's own rows as
+    stored, causal within the chunk with the ragged tail masked out.
     Returns (out (B, T, d), new_cache).
     """
     B, T = x.shape[:2]
-    hd, H, K = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-    G = H // K
-    xc = x.astype(compute_dtype)
-    q = (xc @ p["q_proj"]["kernel"].astype(compute_dtype)).reshape(B, T, K, G, hd)
-    k = (xc @ p["k_proj"]["kernel"].astype(compute_dtype)).reshape(B, T, K, hd)
-    v = (xc @ p["v_proj"]["kernel"].astype(compute_dtype)).reshape(B, T, K, hd)
-    if cfg.qk_norm:
-        q = apply_norm(p["q_norm"], q, "rmsnorm", cfg.norm_eps)
-        k = apply_norm(p["k_norm"], k, "rmsnorm", cfg.norm_eps)
+    hd, K = cfg.resolved_head_dim, cfg.n_kv_heads
     positions = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None]  # (B, T)
-    if cfg.use_rope:
-        qf = rope(q.reshape(B, T, H, hd), positions, cfg.rope_theta,
-                  cfg.rope_scaling)
-        q = qf.reshape(B, T, K, G, hd)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-
-    pool_k, pool_v = cache["k"], cache["v"]
-    n_blocks, page = pool_k.shape[:2]
+    q, k, v = _project_qkv(p, cfg, x, x, positions, positions, compute_dtype)
+    n_blocks, page = cache["k"].shape[:2]
     n_pages = block_tables.shape[1]
-    i = jnp.arange(T)[None, :]                                # (1, T)
-    valid_q = (i < n_valid[:, None]) & (positions < n_pages * page)
+    i = jnp.arange(T)
+    valid_q = (i[None, :] < n_valid[:, None]) & (positions < n_pages * page)
     if active is not None:
         valid_q &= active[:, None]
     pg = jnp.clip(positions // page, 0, n_pages - 1)
     blk = jnp.take_along_axis(block_tables, pg, axis=1)       # (B, T)
-    blk_w = jnp.where(valid_q, blk, n_blocks)                 # pads dropped
-    rows = positions % page
-    if "k_scale" in cache:
-        from repro.quant import dequantize_kv, quantize_kv
-        kq, ksc = quantize_kv(k, str(cfg.kv_dtype))   # (B,T,K,hd), (B,T,K)
-        vq, vsc = quantize_kv(v, str(cfg.kv_dtype))
-        new_cache = {
-            "k": pool_k.at[blk_w, rows].set(kq.astype(pool_k.dtype),
-                                            mode="drop"),
-            "v": pool_v.at[blk_w, rows].set(vq.astype(pool_v.dtype),
-                                            mode="drop"),
-            "k_scale": cache["k_scale"].at[blk_w, rows].set(
-                ksc.astype(cache["k_scale"].dtype), mode="drop"),
-            "v_scale": cache["v_scale"].at[blk_w, rows].set(
-                vsc.astype(cache["v_scale"].dtype), mode="drop"),
-        }
-        # the chunk reads its own rows as stored, as decode and a later
-        # prefix hit read them
-        k = dequantize_kv(kq.astype(pool_k.dtype),
-                          ksc.astype(cache["k_scale"].dtype), compute_dtype)
-        v = dequantize_kv(vq.astype(pool_v.dtype),
-                          vsc.astype(cache["v_scale"].dtype), compute_dtype)
-        k_old = dequantize_kv(pool_k[block_tables],
-                              cache["k_scale"][block_tables],
-                              compute_dtype).reshape(B, n_pages * page, K, hd)
-        v_old = dequantize_kv(pool_v[block_tables],
-                              cache["v_scale"][block_tables],
-                              compute_dtype).reshape(B, n_pages * page, K, hd)
-    else:
-        new_cache = {
-            "k": pool_k.at[blk_w, rows].set(k.astype(pool_k.dtype),
-                                            mode="drop"),
-            "v": pool_v.at[blk_w, rows].set(v.astype(pool_v.dtype),
-                                            mode="drop"),
-        }
-        k_old = pool_k[block_tables].reshape(B, n_pages * page, K, hd)
-        v_old = pool_v[block_tables].reshape(B, n_pages * page, K, hd)
+    blk = jnp.where(valid_q, blk, n_blocks)                   # pads dropped
+    new_cache, stored = _write_kv_rows(cfg, cache, (blk, positions % page),
+                                       k, v)
+    k, v = _read_kv(stored, compute_dtype)
+    k_old, v_old = (a.reshape(B, n_pages * page, K, hd) for a in _read_kv(
+        {n: a[block_tables] for n, a in cache.items()}, compute_dtype))
+    # snapshot rows strictly below the slot's own pos (the prefix-shared
+    # head is readable from 0); intra-chunk causal over the new keys with
+    # the ragged tail masked out
     old_pos = jnp.arange(n_pages * page)
-
-    # per-slot masks: snapshot rows strictly below the slot's own pos (the
-    # prefix-shared head is readable from 0, as in extend), intra-chunk
-    # causal over the new keys with the ragged tail masked out
-    mask_old = jnp.broadcast_to(
-        (old_pos[None, None, :] < pos[:, None, None]),
-        (B, T, old_pos.shape[0]))
-    j = jnp.arange(T)
-    mask_new = ((j[None, None, :] <= j[None, :, None])
-                & (j[None, None, :] < n_valid[:, None, None]))
-    s_old = jnp.einsum("btkgd,bskd->bkgts", q, k_old.astype(compute_dtype),
-                       preferred_element_type=jnp.float32) * _scale(cfg)
-    s_new = jnp.einsum("btkgd,bskd->bkgts", q, k.astype(compute_dtype),
-                       preferred_element_type=jnp.float32) * _scale(cfg)
-    s = softcap(jnp.concatenate([s_old, s_new], axis=-1), cfg.attn_softcap)
+    mask_old = jnp.broadcast_to(old_pos[None, None, :] < pos[:, None, None],
+                                (B, T, old_pos.shape[0]))
+    mask_new = ((i[None, None, :] <= i[None, :, None])
+                & (i[None, None, :] < n_valid[:, None, None]))
     mask = jnp.concatenate([mask_old, mask_new], axis=-1)
-    s = jnp.where(mask[:, None, None], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    vv = jnp.concatenate([v_old, v], axis=1).astype(compute_dtype)
-    out = jnp.einsum("bkgts,bskd->btkgd", w.astype(compute_dtype), vv,
-                     preferred_element_type=jnp.float32)
-    out = out.reshape(B, T, H * hd).astype(compute_dtype)
-    out = (out @ p["o_proj"]["kernel"].astype(compute_dtype)).astype(x.dtype)
-    return out, new_cache
+    return _attend_chunk(p, cfg, q, k_old, v_old, k, v, mask, compute_dtype,
+                         x.dtype), new_cache
 
 
 def attention_extend(p: Params, cfg: ModelConfig, x, cache: dict, *,
                      is_local: bool, pos, n_valid, slot, compute_dtype,
-                     block_tables=None, first_new_pos=0):
-    """Extend ONE slot's cache by up to T tokens (chunked prefill).
+                     first_new_pos=0):
+    """Extend ONE slot's dense or ring-buffer cache by up to T tokens
+    (chunked prefill); paged pools go through ``attention_paged_extend``.
 
     x: (1, T, d) tokens at absolute positions ``pos .. pos+T-1``; the first
     ``n_valid`` are real, the rest are ragged-tail padding — their cache
     writes are dropped (out-of-bounds scatter) and their outputs are junk
-    that the caller slices off. ``cache`` is the POOL entry: dense
-    ``(B, S_buf, K, D)`` buffers, or paged ``(n_blocks, page, K, D)`` pools
-    with ``block_tables`` ((B, P) int32). ``slot`` is this request's slot.
+    that the caller slices off. ``cache`` is the POOL entry, ``(B, S_buf,
+    K, D)`` buffers; ``slot`` is this request's slot.
 
     Attention reads combine a pre-write snapshot of the slot's cache (old
     positions ``< pos``) with the chunk's own K/V under an intra-chunk
@@ -561,136 +538,50 @@ def attention_extend(p: Params, cfg: ModelConfig, x, cache: dict, *,
     the chunk wraps the window.
 
     ``first_new_pos`` (traced scalar) is the absolute position prefill
-    started at: with prefix caching the paged snapshot rows below it were
-    *mapped* from shared blocks (valid, readable mid-sequence), while in
-    the dense layout nothing below it was ever written by this request —
-    the snapshot mask keeps those stale rows of a reused slot out of the
+    started at: nothing below it was written by this request, so the
+    snapshot mask keeps those stale rows of a reused slot out of the
     scores. Returns (out (1, T, d), new_cache).
     """
     T = x.shape[1]
-    hd, H, K = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-    G = H // K
-    xc = x.astype(compute_dtype)
-    q = (xc @ p["q_proj"]["kernel"].astype(compute_dtype)).reshape(1, T, K, G, hd)
-    k = (xc @ p["k_proj"]["kernel"].astype(compute_dtype)).reshape(1, T, K, hd)
-    v = (xc @ p["v_proj"]["kernel"].astype(compute_dtype)).reshape(1, T, K, hd)
-    if cfg.qk_norm:
-        q = apply_norm(p["q_norm"], q, "rmsnorm", cfg.norm_eps)
-        k = apply_norm(p["k_norm"], k, "rmsnorm", cfg.norm_eps)
+    hd, K = cfg.resolved_head_dim, cfg.n_kv_heads
     positions = pos + jnp.arange(T, dtype=jnp.int32)          # (T,) absolute
-    if cfg.use_rope:
-        qf = rope(q.reshape(1, T, H, hd), positions[None], cfg.rope_theta,
-                  cfg.rope_scaling)
-        q = qf.reshape(1, T, K, G, hd)
-        k = rope(k, positions[None], cfg.rope_theta, cfg.rope_scaling)
+    q, k, v = _project_qkv(p, cfg, x, x, positions[None], positions[None],
+                           compute_dtype)
     i = jnp.arange(T)
     valid_q = i < n_valid
-
-    if block_tables is not None:
-        # paged pools: scatter the chunk rows through the slot's block table
-        pool_k, pool_v = cache["k"], cache["v"]
-        n_blocks, page = pool_k.shape[:2]
-        n_pages = block_tables.shape[1]
-        table_row = jax.lax.dynamic_slice(
-            block_tables, (slot, 0), (1, n_pages))[0]         # (P,)
-        blk = table_row[positions // page]
-        blk_w = jnp.where(valid_q, blk, n_blocks)             # pads dropped
-        rows = positions % page
-        if "k_scale" in cache:
-            # quantized pools: per-row absmax quantize the chunk before the
-            # scatter; the pre-write snapshot dequantizes at read
-            from repro.quant import dequantize_kv, quantize_kv
-            kq, ksc = quantize_kv(k[0], str(cfg.kv_dtype))    # (T,K,hd),(T,K)
-            vq, vsc = quantize_kv(v[0], str(cfg.kv_dtype))
-            new_cache = {
-                "k": pool_k.at[blk_w, rows].set(kq.astype(pool_k.dtype),
-                                                mode="drop"),
-                "v": pool_v.at[blk_w, rows].set(vq.astype(pool_v.dtype),
-                                                mode="drop"),
-                "k_scale": cache["k_scale"].at[blk_w, rows].set(
-                    ksc.astype(cache["k_scale"].dtype), mode="drop"),
-                "v_scale": cache["v_scale"].at[blk_w, rows].set(
-                    vsc.astype(cache["v_scale"].dtype), mode="drop"),
-            }
-            # the chunk reads its own rows as stored, as decode and a later
-            # prefix hit read them
-            k = dequantize_kv(kq.astype(pool_k.dtype),
-                              ksc.astype(cache["k_scale"].dtype),
-                              compute_dtype)[None]
-            v = dequantize_kv(vq.astype(pool_v.dtype),
-                              vsc.astype(cache["v_scale"].dtype),
-                              compute_dtype)[None]
-            k_old = dequantize_kv(pool_k[table_row],
-                                  cache["k_scale"][table_row],
-                                  compute_dtype).reshape(
-                                      1, n_pages * page, K, hd)
-            v_old = dequantize_kv(pool_v[table_row],
-                                  cache["v_scale"][table_row],
-                                  compute_dtype).reshape(
-                                      1, n_pages * page, K, hd)
-        else:
-            new_cache = {
-                "k": pool_k.at[blk_w, rows].set(k[0].astype(pool_k.dtype),
-                                                mode="drop"),
-                "v": pool_v.at[blk_w, rows].set(v[0].astype(pool_v.dtype),
-                                                mode="drop"),
-            }
-            k_old = pool_k[table_row].reshape(1, n_pages * page, K, hd)
-            v_old = pool_v[table_row].reshape(1, n_pages * page, K, hd)
-        old_pos = jnp.arange(n_pages * page)                  # absolute
+    S_buf = cache["k"].shape[1]
+    is_ring = is_local and cfg.window and S_buf == cfg.window
+    k_old = jax.lax.dynamic_slice(cache["k"], (slot, 0, 0, 0),
+                                  (1, S_buf, K, hd))
+    v_old = jax.lax.dynamic_slice(cache["v"], (slot, 0, 0, 0),
+                                  (1, S_buf, K, hd))
+    j = jnp.arange(S_buf)
+    if is_ring:
+        # ring slot j held absolute position (pos-1) - ((pos-1-j) mod W)
+        # before this chunk; only the last min(W, n_valid) chunk rows
+        # are written (earlier rows would be overwritten by the wrap)
+        old_pos = (pos - 1) - jnp.mod(pos - 1 - j, S_buf)
+        w_ok = valid_q & (i >= n_valid - S_buf)
+        rows = jnp.where(w_ok, positions % S_buf, S_buf)
     else:
-        S_buf = cache["k"].shape[1]
-        is_ring = is_local and cfg.window and S_buf == cfg.window
-        k_slot = jax.lax.dynamic_slice(cache["k"], (slot, 0, 0, 0),
-                                       (1, S_buf, K, hd))
-        v_slot = jax.lax.dynamic_slice(cache["v"], (slot, 0, 0, 0),
-                                       (1, S_buf, K, hd))
-        k_old, v_old = k_slot, v_slot
-        j = jnp.arange(S_buf)
-        if is_ring:
-            # ring slot j held absolute position (pos-1) - ((pos-1-j) mod W)
-            # before this chunk; only the last min(W, n_valid) chunk rows
-            # are written (earlier rows would be overwritten by the wrap)
-            old_pos = (pos - 1) - jnp.mod(pos - 1 - j, S_buf)
-            w_ok = valid_q & (i >= n_valid - S_buf)
-            rows = jnp.where(w_ok, positions % S_buf, S_buf)
-        else:
-            old_pos = j
-            rows = jnp.where(valid_q, positions, S_buf)
-        k_new = k_slot.at[0, rows].set(k[0].astype(k_slot.dtype), mode="drop")
-        v_new = v_slot.at[0, rows].set(v[0].astype(v_slot.dtype), mode="drop")
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice(cache["k"], k_new,
-                                              (slot, 0, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(cache["v"], v_new,
-                                              (slot, 0, 0, 0)),
-        }
-
+        old_pos = j
+        rows = jnp.where(valid_q, positions, S_buf)
+    k_new = k_old.at[0, rows].set(k[0].astype(k_old.dtype), mode="drop")
+    v_new = v_old.at[0, rows].set(v[0].astype(v_old.dtype), mode="drop")
+    new_cache = {
+        "k": jax.lax.dynamic_update_slice(cache["k"], k_new, (slot, 0, 0, 0)),
+        "v": jax.lax.dynamic_update_slice(cache["v"], v_new, (slot, 0, 0, 0)),
+    }
     # scores over [old snapshot | chunk] keys; masks are (T, S_old) / (T, T)
-    # — paged snapshots are readable from position 0 (prefix-shared blocks
-    # hold valid rows below first_new_pos); dense snapshots only from
-    # first_new_pos (rows below it belong to the slot's previous occupant)
-    snap_lo = 0 if block_tables is not None else first_new_pos
-    mask_old = ((old_pos >= snap_lo) & (old_pos < pos))[None, :]
-    mask_old = jnp.broadcast_to(mask_old, (T, old_pos.shape[0]))
+    mask_old = ((old_pos >= first_new_pos) & (old_pos < pos))[None, :]
+    mask_old = jnp.broadcast_to(mask_old, (T, S_buf))
     mask_new = i[None, :] <= i[:, None]                       # intra-chunk
     if is_local and cfg.window:
         mask_old = mask_old & (old_pos[None, :] > positions[:, None] - cfg.window)
         mask_new = mask_new & (i[:, None] - i[None, :] < cfg.window)
-    s_old = jnp.einsum("btkgd,bskd->bkgts", q, k_old.astype(compute_dtype),
-                       preferred_element_type=jnp.float32) * _scale(cfg)
-    s_new = jnp.einsum("btkgd,bskd->bkgts", q, k.astype(compute_dtype),
-                       preferred_element_type=jnp.float32) * _scale(cfg)
-    s = softcap(jnp.concatenate([s_old, s_new], axis=-1), cfg.attn_softcap)
-    mask = jnp.concatenate([mask_old, mask_new], axis=-1)
-    s = jnp.where(mask[None, None, None], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    vv = jnp.concatenate([v_old, v], axis=1).astype(compute_dtype)
-    out = jnp.einsum("bkgts,bskd->btkgd", w.astype(compute_dtype), vv,
-                     preferred_element_type=jnp.float32)
-    out = out.reshape(1, T, H * hd).astype(compute_dtype)
-    out = (out @ p["o_proj"]["kernel"].astype(compute_dtype)).astype(x.dtype)
-    return out, new_cache
+    mask = jnp.concatenate([mask_old, mask_new], axis=-1)[None]
+    return _attend_chunk(p, cfg, q, k_old, v_old, k, v, mask, compute_dtype,
+                         x.dtype), new_cache
 
 
 # --------------------------------------------------------------------------

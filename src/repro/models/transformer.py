@@ -154,11 +154,14 @@ def _apply_layer(lp: Params, spec: LayerSpec, cfg: ModelConfig, x, *,
     attention layers. Extend extras: ``slot``/``n_valid``/``first_new_pos``
     (traced scalars) — ``first_new_pos`` is where this request's prefill
     started (> 0 when a prefix-cache hit mapped the head of the sequence
-    from shared blocks). ``layer`` (decode, and extend of latent layers;
-    traced scalar): the entry's paged pools are stacked over the scanned
-    layers and this layer lives at that index. Latent-attention (``mla``)
-    layers refuse ``verify``. Expert layers run drop-free in every mode but
-    ``full``. Returns (x, new_cache_entry, aux_loss).
+    from shared blocks; dense caches mask the rows below it). Paged full-
+    attention layers score ``extend`` (the slot's table row) and ``verify``
+    through one function, ``attention_paged_extend``. ``layer`` (decode,
+    and extend of latent layers; traced scalar): the entry's paged pools
+    are stacked over the scanned layers and this layer lives at that
+    index. Latent-attention (``mla``) layers refuse ``verify``. Expert
+    layers run drop-free in every mode but ``full``. Returns (x,
+    new_cache_entry, aux_loss).
     """
     aux = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
@@ -197,23 +200,29 @@ def _apply_layer(lp: Params, spec: LayerSpec, cfg: ModelConfig, x, *,
                 compute_dtype=compute_dtype, part=part)
             if cache is not None:
                 new_cache["self"] = _store_kv(cfg, k, v, is_local, cache["self"])
+        elif mode in ("extend", "verify") and bt is not None:
+            # paged chunked prefill (one slot, through its table row) and
+            # verification (every slot) score new tokens alike
+            if mode == "extend":
+                tables = jax.lax.dynamic_slice(bt, (slot, 0),
+                                               (1, bt.shape[1]))
+                pos_b, n_b, act = (jnp.reshape(pos, (1,)),
+                                   jnp.reshape(n_valid, (1,)), None)
+            else:
+                tables, pos_b, n_b, act = bt, pos, n_valid, active
+            out, new_cache["self"] = attn_mod.attention_paged_extend(
+                lp["attn"], cfg, h, cache["self"], pos=pos_b, n_valid=n_b,
+                active=act, block_tables=tables, compute_dtype=compute_dtype)
         elif mode == "extend":
-            out, new_self = attn_mod.attention_extend(
+            out, new_cache["self"] = attn_mod.attention_extend(
                 lp["attn"], cfg, h, cache["self"], is_local=is_local, pos=pos,
                 n_valid=n_valid, slot=slot, compute_dtype=compute_dtype,
-                block_tables=bt, first_new_pos=first_new_pos)
-            new_cache["self"] = new_self
+                first_new_pos=first_new_pos)
         elif mode == "verify":
-            if bt is None:
-                raise NotImplementedError(
-                    "verify_step requires the paged layout on every "
-                    "attention layer (speculative decoding is gated on "
-                    "paged all-full-attention configs)")
-            out, new_self = attn_mod.attention_verify(
-                lp["attn"], cfg, h, cache["self"], pos=pos, n_valid=n_valid,
-                active=active, block_tables=bt,
-                compute_dtype=compute_dtype)
-            new_cache["self"] = new_self
+            raise NotImplementedError(
+                "verify_step requires the paged layout on every "
+                "attention layer (speculative decoding is gated on "
+                "paged all-full-attention configs)")
         else:
             out, new_self = attn_mod.attention_decode(
                 lp["attn"], cfg, h, cache["self"], is_local=is_local, pos=pos,
@@ -624,8 +633,10 @@ def verify_step(params, cfg: ModelConfig, cache, tokens, pos, n_valid, *,
     ragged tail (padded rows write nothing); ``active`` ((B,) bool) gates
     whole slots exactly like ``decode_step``. Paged all-full-attention
     configs only (the serve engine gates speculation on the same predicate
-    as the prefix cache). T is static, so one compiled shape serves every
-    scheduler turn at a given ``spec_k``.
+    as the prefix cache); each layer scores through
+    ``attention_paged_extend``, as a paged prefill chunk does. T is
+    static, so one compiled shape serves every scheduler turn at a given
+    ``spec_k``.
 
     Returns (logits (B, T, V) — row t scores position ``pos+t``'s NEXT
     token — and the new cache with all T KV rows written; the engine rolls

@@ -114,8 +114,8 @@ def test_softcap_monotone():
 
 
 def test_pallas_attention_impl_matches_xla():
-    """cfg.attention_impl='pallas_interpret' routes the model through the
-    Pallas kernel (interpret mode) and must equal the XLA flash path."""
+    """cfg.kernel_backend='interpret' routes the model through the Pallas
+    kernel (interpret mode) and must equal the XLA flash path."""
     import numpy as np
     from repro.configs import get_arch, reduced
     from repro.models import forward, init
@@ -124,8 +124,77 @@ def test_pallas_attention_impl_matches_xla():
     params = init(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 32)), jnp.int32)
-    h_xla, _, _ = forward(params, cfg.replace(attention_impl="xla"), toks)
-    h_pl, _, _ = forward(params, cfg.replace(attention_impl="pallas_interpret"),
+    h_xla, _, _ = forward(params, cfg.replace(kernel_backend=""), toks)
+    h_pl, _, _ = forward(params, cfg.replace(kernel_backend="interpret"),
                          toks)
     np.testing.assert_allclose(np.asarray(h_xla), np.asarray(h_pl),
                                rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+@pytest.mark.parametrize("shape", ["chunk", "verify"])
+def test_paged_extend_matches_token_by_token_decode(shape, kv_dtype):
+    """``attention_paged_extend`` scores T new tokens as T single-token
+    ``attention_decode`` steps on the paged pool would, and writes the same
+    rows: a chunk of one slot through its table row with a ragged tail, and
+    verification of three slots (different ``pos``/``n_valid``, one
+    inactive, one running past its table)."""
+    from repro.configs import get_arch, reduced
+    from repro.models.attention import (attention_decode, attention_init,
+                                        attention_paged_extend)
+    from repro.quant import quantize_kv
+
+    cfg = reduced(get_arch("qwen3-0.6b")).replace(
+        d_model=128, n_heads=4, n_kv_heads=2, head_dim=32, dtype="float32",
+        kv_dtype=kv_dtype)
+    K, hd, N, page, P, T = 2, 32, 14, 4, 4, 4
+    p = attention_init(jax.random.PRNGKey(0), cfg, jnp.float32)
+    tables = jnp.arange(1, 13, dtype=jnp.int32).reshape(3, P)
+    kk, kv, kx = jax.random.split(jax.random.PRNGKey(1), 3)
+    pool = {"k": jax.random.normal(kk, (N, page, K, hd)),
+            "v": jax.random.normal(kv, (N, page, K, hd))}
+    if kv_dtype:
+        for n in ("k", "v"):
+            pool[n], pool[n + "_scale"] = quantize_kv(pool[n], kv_dtype)
+    if shape == "chunk":
+        tables = jax.lax.dynamic_slice(tables, (1, 0), (1, P))
+        pos, n_valid, active = np.array([5]), np.array([3]), None
+    else:
+        pos, n_valid = np.array([3, 9, 13]), np.array([3, 2, 4])
+        active = np.array([True, False, True])
+    B = tables.shape[0]
+    x = jax.random.normal(kx, (B, T, cfg.d_model))
+    t = np.arange(T)
+    valid = (t[None] < n_valid[:, None]) & (pos[:, None] + t < P * page)
+    if active is not None:
+        valid &= active[:, None]
+    out, got = attention_paged_extend(
+        p, cfg, x, pool, pos=jnp.asarray(pos, jnp.int32),
+        n_valid=jnp.asarray(n_valid, jnp.int32),
+        active=None if active is None else jnp.asarray(active),
+        block_tables=tables, compute_dtype=jnp.float32)
+
+    want, outs = pool, []
+    for i in range(T):
+        o, want = attention_decode(
+            p, cfg, x[:, i:i + 1], want, is_local=False,
+            pos=jnp.asarray(pos + i, jnp.int32), compute_dtype=jnp.float32,
+            active=jnp.asarray(valid[:, i]), block_tables=tables)
+        outs.append(o)
+    tol = 5e-4 if kv_dtype else 1e-5
+    np.testing.assert_allclose(
+        np.asarray(out)[valid], np.asarray(jnp.concatenate(outs, 1))[valid],
+        rtol=tol, atol=tol)
+
+    # the pool: the valid rows as decode wrote them, every other row as it
+    # was (padding, the inactive slot, positions past the table)
+    written = np.zeros((N, page), bool)
+    for b, i in zip(*np.nonzero(valid)):
+        q = pos[b] + i
+        written[np.asarray(tables)[b, q // page], q % page] = True
+    assert written.sum() == valid.sum()
+    for n in pool:
+        np.testing.assert_array_equal(np.asarray(got[n])[written],
+                                      np.asarray(want[n])[written])
+        np.testing.assert_array_equal(np.asarray(got[n])[~written],
+                                      np.asarray(pool[n])[~written])

@@ -1,10 +1,8 @@
 """Backend-registry dispatch: parity across every registered op and dtype,
 capability negotiation (unsupported requests fall to ref, never error),
-``use_backend`` scoping, block-size tuning, and the ``attention_impl``
-deprecation shim."""
+``use_backend`` scoping, block-size tuning, and kernel-backend
+validation."""
 from __future__ import annotations
-
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -313,53 +311,16 @@ def test_caller_kwargs_beat_tuning_table():
 
 
 # --------------------------------------------------------------------------
-# model-layer integration + attention_impl deprecation shim
+# model-layer integration
 # --------------------------------------------------------------------------
 def _tiny_cfg(**kw):
     from repro.configs import get_arch, reduced
     return reduced(get_arch("gemma2-27b")).replace(dtype="float32", **kw)
 
 
-def test_attention_impl_shim_warns_and_maps():
-    with pytest.warns(DeprecationWarning):
-        cfg = ModelConfig(attention_impl="pallas_interpret")
-    assert cfg.resolved_kernel_backend == "interpret"
-    with pytest.warns(DeprecationWarning):
-        cfg = ModelConfig(attention_impl="pallas")
-    assert cfg.resolved_kernel_backend == "pallas"
-    # explicit kernel_backend wins over the deprecated field
-    with pytest.warns(DeprecationWarning):
-        cfg = ModelConfig(attention_impl="pallas", kernel_backend="ref")
-    assert cfg.resolved_kernel_backend == "ref"
-    # the shim round-trips: setting the deprecated field back to "xla"
-    # restores the XLA paths
-    with pytest.warns(DeprecationWarning):
-        legacy = ModelConfig(attention_impl="pallas_interpret")
-    assert legacy.replace(attention_impl="xla").resolved_kernel_backend == ""
+def test_kernel_backend_is_validated():
     with pytest.raises(ValueError):
         ModelConfig(kernel_backend="cuda")
-    with pytest.raises(ValueError):
-        ModelConfig(attention_impl="flash3")
-
-
-def test_attention_impl_shim_still_routes_model():
-    """The deprecated switch must still drive the registry path end-to-end."""
-    from repro.models import forward, init
-
-    cfg = _tiny_cfg()
-    params = init(jax.random.PRNGKey(0), cfg)
-    toks = jnp.asarray(np.random.default_rng(0).integers(
-        0, cfg.vocab_size, (2, 24)), jnp.int32)
-    h_xla, _, _ = forward(params, cfg, toks)
-    with pytest.warns(DeprecationWarning):
-        legacy = cfg.replace(attention_impl="pallas_interpret")
-    h_old, _, _ = forward(params, legacy, toks)
-    h_new, _, _ = forward(params, cfg.replace(kernel_backend="interpret"),
-                          toks)
-    np.testing.assert_allclose(np.asarray(h_old), np.asarray(h_new),
-                               rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(h_xla), np.asarray(h_new),
-                               rtol=2e-3, atol=2e-3)
 
 
 def test_use_backend_scope_overrides_model_config():
